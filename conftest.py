@@ -36,15 +36,3 @@ def _clear_jax_caches_per_module():
     import jax
 
     jax.clear_caches()
-
-
-@pytest.fixture(autouse=True)
-def _reset_kmeans_fallback_warnings():
-    """Warn-once state must not leak across tests (repro.core.kmeans keeps a
-    module-level registry so the fallback notice fires once per process)."""
-    yield
-    try:
-        from repro.core.kmeans import reset_fallback_warnings
-    except ImportError:  # collection of non-repro test files
-        return
-    reset_fallback_warnings()

@@ -16,9 +16,11 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.kmeans import KMeansConfig, kmeans
+from repro.launch.cache import enable_compile_cache
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--candidates", type=int, default=100_000)
     ap.add_argument("--dim", type=int, default=64)

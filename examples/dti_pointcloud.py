@@ -27,9 +27,11 @@ import jax
 from repro.core.spectral import EigConfig, GraphConfig, KMeansConfig, SpectralPipeline
 from repro.core.similarity import build_similarity_graph
 from repro.data.pointcloud import dti_like_pointcloud
+from repro.launch.cache import enable_compile_cache
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true", help="paper-scale: 142k voxels, k=500")
     ap.add_argument("--n", type=int, default=4000)

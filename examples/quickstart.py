@@ -16,6 +16,7 @@ import jax
 from repro.core.reduce import CoarsenConfig, SparsifyConfig
 from repro.core.spectral import EigConfig, SpectralPipeline
 from repro.data.sbm import sbm_graph
+from repro.launch.cache import enable_compile_cache
 
 
 def purity(labels, truth) -> float:
@@ -26,6 +27,7 @@ def purity(labels, truth) -> float:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--clusters", type=int, default=8)
     ap.add_argument("--n-per", type=int, default=200)

@@ -19,6 +19,7 @@ from repro.models import transformer as tfm
 from repro.optim.adamw import AdamWConfig
 from repro.train.loop import TrainLoopConfig, run_training
 from repro.train.state import init_state, make_train_step
+from repro.launch.cache import enable_compile_cache
 
 PRESETS = {
     # ~5M params: CPU-friendly demo
@@ -36,6 +37,7 @@ PRESETS = {
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--preset", choices=PRESETS, default="tiny")
     ap.add_argument("--steps", type=int, default=200)

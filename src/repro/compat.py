@@ -1,7 +1,7 @@
-"""Version compatibility shims (single home — keep all copies here).
+"""The one home of jax API names that have moved between releases.
 
-``shard_map`` moved to the jax top level (and ``check_rep`` became
-``check_vma``) in jax 0.5; the container pins 0.4.x.  Import from here so
+The supported stack is jax/jaxlib 0.9.0.  ``shard_map`` lives at the jax top
+level there, and its replication check is ``check_vma``.  Import from here so
 the next rename is a one-file fix:
 
     from repro.compat import shard_map, SHARD_MAP_NO_CHECK
@@ -10,42 +10,5 @@ from __future__ import annotations
 
 import jax
 
-try:  # jax >= 0.5
-    shard_map = jax.shard_map
-    SHARD_MAP_NO_CHECK = {"check_vma": False}
-except AttributeError:  # pragma: no cover - version-dependent
-    from jax.experimental.shard_map import shard_map  # noqa: F401
-
-    SHARD_MAP_NO_CHECK = {"check_rep": False}
-
-
-def _version_tuple(version: str) -> tuple:
-    parts = []
-    for p in version.split(".")[:3]:
-        digits = "".join(c for c in p if c.isdigit())
-        if not digits:
-            break
-        parts.append(int(digits))
-    return tuple(parts)
-
-
-def needs_argsort_gather_workaround(version: str | None = None) -> bool:
-    """True while the pinned jax still miscompiles argsort-gather on
-    partially-replicated operands (psum-doubling across unmentioned mesh
-    axes; observed on 0.4.x CPU).  Gates the Stage-1 re-replication
-    workaround in :mod:`repro.core.spectral` — once the pin moves to
-    jax >= 0.5 this returns False and the extra all-gather disappears
-    automatically.
-
-    Re-checked against the pinned jax 0.4.37 (8 virtual CPU devices,
-    ``jax.make_mesh((4, 2), ("data", "model"))``): forcing this predicate to
-    False and running the sharded raw-points pipeline
-    (``spectral_cluster_from_points_sharded``, the
-    test_sharded_points_stage1 workload) drops blob purity from > 0.95 to
-    0.42 — the [n, k] kNN results feeding graph assembly are left partially
-    replicated over the unmentioned "model" axis and the argsort gather
-    psum-doubles.  The workaround is still required at this pin; do not
-    delete it before the jax bump, just re-run the forced-off experiment.
-    """
-    v = _version_tuple(jax.__version__ if version is None else version)
-    return v < (0, 5)
+shard_map = jax.shard_map
+SHARD_MAP_NO_CHECK = {"check_vma": False}

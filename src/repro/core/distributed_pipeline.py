@@ -52,7 +52,9 @@ from repro.kernels.lsh_candidates.ops import (
 )
 from repro.sparse.distributed import (  # noqa: F401  (normalize_sharded re-export)
     ShardedCOO,
+    auto_mesh,
     normalize_sharded,
+    padded_rows,
     ring_shift,
 )
 
@@ -133,7 +135,12 @@ def make_knn_rowblock(mesh, k: int, *, axis: str = "data", block_q: int = 1024,
     at fixed per-shard rows.
 
     Returns ``knn(x) -> (dist² [n, k], idx [n, k])`` with rows sharded over
-    ``axis``; outputs feed :func:`repro.core.similarity.graph_from_knn`.
+    ``axis``; outputs feed :func:`repro.core.similarity.graph_from_knn`.  Any
+    n works: rows are padded to equal blocks (as
+    :func:`~repro.sparse.distributed.partition_coo_by_rows` pads graphs)
+    with a point farther from every real point than any two real points are
+    from each other, so pads never enter a real row's top-k (they could only
+    when n − 1 < k, and are masked to (+inf, −1) then); pad rows are dropped.
     """
     if method not in ("exact", "lsh"):
         raise ValueError(
@@ -142,6 +149,7 @@ def make_knn_rowblock(mesh, k: int, *, axis: str = "data", block_q: int = 1024,
         raise ValueError(
             f"make_knn_rowblock exchange must be one of {_EXCHANGES}, got "
             f"{exchange!r}")
+    mesh = auto_mesh(mesh)
     m = default_candidates(k, n_tables) if candidates is None else candidates
     n_shards = _axis_size(mesh, axis)
 
@@ -154,7 +162,7 @@ def make_knn_rowblock(mesh, k: int, *, axis: str = "data", block_q: int = 1024,
         # explicitly sharded over `axis`, so the check adds nothing here.
         **SHARD_MAP_NO_CHECK,
     )
-    def knn(x_blk):
+    def search(x_blk):
         if exchange == "ring":
             return _knn_ring(x_blk)
         x_full = jax.lax.all_gather(x_blk, axis, axis=0, tiled=True)
@@ -216,6 +224,20 @@ def make_knn_rowblock(mesh, k: int, *, axis: str = "data", block_q: int = 1024,
             if t < S - 1:
                 payload = ring_shift(payload, axis, S)
         return best_d, best_i
+
+    def knn(x):
+        n = x.shape[0]
+        n_pad = padded_rows(n, n_shards)
+        if n_pad == n:
+            return search(x)
+        # |coordinates| <= M puts real pairs within d·(2M)² and pads at
+        # least d·(3M+1)² from every real point
+        far = 4.0 * jnp.max(jnp.abs(x.astype(jnp.float32))) + 1.0
+        dist2, idx = search(jnp.concatenate(
+            [x, jnp.full((n_pad - n, x.shape[1]), far, x.dtype)]))
+        pad_hit = idx[:n] >= n
+        return (jnp.where(pad_hit, jnp.inf, dist2[:n]),
+                jnp.where(pad_hit, -1, idx[:n]))
 
     return knn
 
@@ -288,9 +310,12 @@ def kmeans_sharded(
     data (the parity test in tests/test_distributed.py pins it).  Needs
     ``n // S >= k`` rows per shard so each shard can fill its slice.
 
-    ``x.shape[0]`` must divide evenly by the mesh axis size.  Seeding runs
-    on the global (GSPMD-sharded) array — ``row_at``'s one-hot contractions
-    already shard cleanly.
+    Any n works: rows are padded with zeros to equal blocks (as
+    :func:`~repro.sparse.distributed.partition_coo_by_rows` pads graphs).  A
+    zero row adds nothing to Σx; its count, label change and distance are
+    masked out of the packed statistics, and pad labels are dropped.
+    Seeding runs on the unpadded global (GSPMD-sharded) array — ``row_at``'s
+    one-hot contractions already shard cleanly.
     """
     if cfg.iter != "fused":
         raise ValueError(
@@ -300,17 +325,20 @@ def kmeans_sharded(
     if cfg.k is None:
         raise ValueError("KMeansConfig.k is unset — standalone kmeans_sharded "
                          "needs an explicit k (use cfg.resolved(k))")
+    mesh = auto_mesh(mesh)
     axes = _axis_tuple(axis)
     n, d = x.shape
     k = cfg.k
     n_shards = _axis_size(mesh, axes)
-    assert n % n_shards == 0, (n, mesh.shape)
-    if cfg.empty == "reseed_farthest" and n // n_shards < k:
+    n_pad = padded_rows(n, n_shards)
+    if cfg.empty == "reseed_farthest" and n_pad // n_shards < k:
         raise ValueError(
             f"KMeansConfig(empty='reseed_farthest') under kmeans_sharded "
             f"needs at least k rows per shard (each shard contributes k "
-            f"farthest-point candidates): n//S = {n // n_shards} < k = {k}")
+            f"farthest-point candidates): n//S = {n_pad // n_shards} < k = {k}")
     c0 = km.seed_centroids(x, cfg, key) if init_centroids is None else init_centroids
+    if n_pad != n:
+        x = jnp.concatenate([x, jnp.zeros((n_pad - n, d), x.dtype)])
 
     @partial(
         _shard_map,
@@ -347,11 +375,19 @@ def kmeans_sharded(
             _, sel = jax.lax.top_k(buf[:, d], k)
             return buf[sel, :d]  # [k, d] donors, farthest first
 
+        nl = x_blk.shape[0]
+        real = (shard_index() * nl + jnp.arange(nl) < n) if n_pad != n else None
+
         def one_iter(c, labels):
             new_labels, dmin, sums, counts = km.lloyd_iter(x_blk, c, x_norm, cfg)
-            changed_pc = jax.ops.segment_sum(
-                (new_labels != labels).astype(jnp.float32), new_labels,
-                num_segments=k)
+            change = (new_labels != labels).astype(jnp.float32)
+            if real is not None:  # zero pad rows: uncount, unchange, no donor
+                counts = counts - jax.ops.segment_sum(
+                    (~real).astype(counts.dtype), new_labels, num_segments=k)
+                change = jnp.where(real, change, 0.0)
+                dmin = jnp.where(real, dmin, -1.0)
+            changed_pc = jax.ops.segment_sum(change, new_labels,
+                                             num_segments=k)
             packed = jnp.concatenate(
                 [sums, counts[:, None], changed_pc[:, None]], axis=1)
             packed = jax.lax.psum(packed, axes)  # the iteration's one collective
@@ -391,12 +427,14 @@ def kmeans_sharded(
                 (c0, labels0, jnp.zeros_like(x_norm), jnp.asarray(float(n)),
                  jnp.asarray(0)))
 
+        if real is not None:
+            dmin = jnp.where(real, dmin, 0.0)
         inertia = jax.lax.psum(dmin.sum(), axes)  # once, outside the loop
         return labels, c, inertia, iters, changed
 
     labels, c, inertia, iters, changed = run(x, c0)
     return km.KMeansResult(
-        labels=labels,
+        labels=labels[:n],
         centroids=c.astype(x.dtype),
         inertia=inertia,
         iterations=iters,

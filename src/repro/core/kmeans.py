@@ -31,7 +31,6 @@ single [k, d+1] all-reduce per iteration; the explicit-collective variant
 from __future__ import annotations
 
 import dataclasses
-import warnings
 from functools import partial
 from typing import NamedTuple, Optional
 
@@ -98,27 +97,6 @@ class KMeansConfig:
 
 
 # ---------------------------------------------------------------------------
-# warn-once plumbing (fixture-resettable — the old module-global bool leaked
-# warn-once state across tests)
-# ---------------------------------------------------------------------------
-
-_FALLBACK_WARNED: set = set()
-
-
-def reset_fallback_warnings() -> None:
-    """Clear the warn-once registry (test fixtures; mirrors
-    ``warnings.resetwarnings`` semantics for our fallback notices)."""
-    _FALLBACK_WARNED.clear()
-
-
-def _warn_fallback_once(key: str, message: str) -> None:
-    if key in _FALLBACK_WARNED:
-        return
-    _FALLBACK_WARNED.add(key)
-    warnings.warn(message, RuntimeWarning, stacklevel=3)
-
-
-# ---------------------------------------------------------------------------
 # assignment step (two-pass mode)
 # ---------------------------------------------------------------------------
 
@@ -135,24 +113,17 @@ def assign_ref(x: Array, c: Array, x_norm: Optional[Array] = None):
 
 
 def _assign(x, c, x_norm, cfg: KMeansConfig):
-    # Only unavailability (missing/unported kernel) may fall back under
-    # "auto" — a bare except here would silently mask real kernel bugs as a
-    # slow reference path.  Anything else propagates.
-    if cfg.assign in ("fused", "auto"):
-        try:
-            from repro.kernels.kmeans_assign.ops import kmeans_assign as fused
+    """Two-pass assignment.  ``"auto"``/``"fused"`` call the fused kernel
+    wrapper, whose own dispatch picks Pallas on TPU and the reference
+    elsewhere; ``"ref"`` is the materialized reference.  Nothing falls back:
+    a kernel the compiler refuses raises rather than running the reference
+    unannounced."""
+    if cfg.assign == "ref":
+        return assign_ref(x, c, x_norm)
+    from repro.kernels.kmeans_assign import ops
 
-            return fused(x, c, x_norm=x_norm, block_q=cfg.block_q,
-                         block_k=cfg.block_k, interpret=cfg.interpret)
-        except (ImportError, NotImplementedError) as e:
-            if cfg.assign == "fused":
-                raise
-            _warn_fallback_once(
-                "kmeans_assign",
-                f"fused kmeans_assign kernel unavailable ({e!r}); "
-                "falling back to the reference assignment path",
-            )
-    return assign_ref(x, c, x_norm)
+    return ops.kmeans_assign(x, c, x_norm=x_norm, block_q=cfg.block_q,
+                             block_k=cfg.block_k, interpret=cfg.interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -163,14 +134,28 @@ def lloyd_iter(x: Array, c: Array, x_norm: Optional[Array], cfg: KMeansConfig):
     """One Lloyd iteration's statistics ``(labels, dmin, sums, counts)``
     from a single pass over ``x`` — see :mod:`repro.kernels.kmeans_iter`.
 
-    Unavailability of the Pallas kernel is handled inside the wrapper (the
-    chunked online path is a peer implementation, not a degraded shim), so
-    there is nothing to warn about here; genuine kernel bugs propagate.
+    The wrapper picks its engine from the shapes before the call
+    (:func:`repro.kernels.kmeans_iter.ops.kmeans_iter_engine` names it); the
+    chunked online path is a peer implementation, not a degraded shim.
     """
     from repro.kernels.kmeans_iter.ops import kmeans_iter
 
     return kmeans_iter(x, c, x_norm=x_norm, block_q=cfg.block_q,
                        block_k=cfg.block_k, interpret=cfg.interpret)
+
+
+def runs_mosaic(n: int, d: int, cfg: KMeansConfig) -> bool:
+    """Whether Stage 3 on ``x: [n, d]`` compiles a Mosaic (TPU Pallas)
+    kernel.  GSPMD cannot partition one: in a program over several devices
+    it must sit inside a ``shard_map``."""
+    if cfg.iter == "fused":
+        from repro.kernels.kmeans_iter.ops import kmeans_iter_engine
+
+        return kmeans_iter_engine(
+            n, d, cfg.k, interpret=cfg.interpret, block_q=cfg.block_q,
+            block_k=cfg.block_k) == "pallas"
+    return (cfg.assign != "ref" and not cfg.interpret
+            and jax.default_backend() == "tpu")
 
 
 def centroids_from_sums(sums: Array, counts: Array, prev: Array) -> Array:

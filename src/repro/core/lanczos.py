@@ -236,16 +236,22 @@ def eigsh(op, cfg, *, v0: Optional[Array] = None,
     runs the polynomial-filter embedding
     (:func:`repro.core.chebyshev.chebyshev_eigsh`) — same operator contract,
     same :class:`LanczosResult` out.
+
+    Every matmul the solvers trace runs at full fp32.  A TPU's default is
+    one bf16 pass, which leaves Gram-Schmidt ~1e-3 short of orthogonal: on
+    near-degenerate spectra (k separated blobs) Lanczos then stalls above
+    ``tol`` through every escalation.
     """
     from repro.core.chebyshev import ChebConfig, chebyshev_eigsh
 
-    if isinstance(cfg, ChebConfig):
-        return chebyshev_eigsh(op, cfg, v0=v0, key=key)
-    n = op.shape[0]
-    validate_basis(cfg, n)
-    if cfg.block_size > 1:
-        return _lanczos_topk_block(op.mm, n, cfg, v0=v0, key=key)
-    return _lanczos_topk_single(op.mv, n, cfg, v0=v0, key=key)
+    with jax.default_matmul_precision("float32"):
+        if isinstance(cfg, ChebConfig):
+            return chebyshev_eigsh(op, cfg, v0=v0, key=key)
+        n = op.shape[0]
+        validate_basis(cfg, n)
+        if cfg.block_size > 1:
+            return _lanczos_topk_block(op.mm, n, cfg, v0=v0, key=key)
+        return _lanczos_topk_single(op.mv, n, cfg, v0=v0, key=key)
 
 
 def lanczos_topk(

@@ -91,8 +91,9 @@ jax.tree_util.register_dataclass(CooOperator, ["a"], ["mesh"])
 @dataclasses.dataclass(frozen=True)
 class BlockEllOperator:
     """BlockELL(+COO tail) operator: dense strided ELL-body loads, with the
-    multi-vector ``mm`` dispatching to the Pallas ``ell_spmm`` kernel on TPU
-    (``impl``/``interpret`` mirror the kernel wrapper's knobs)."""
+    multi-vector ``mm`` going through the ``ell_spmm`` wrapper, which runs
+    its XLA path on TPU until the kernel compiles there (``impl``/
+    ``interpret`` mirror the wrapper's knobs)."""
 
     a: BlockELL
     impl: str = "auto"  # "auto" | "pallas" | "ref"

@@ -52,7 +52,6 @@ import repro.core.kmeans as km
 import repro.core.lanczos as lz
 import repro.core.laplacian as lap
 from repro.core.health import HealthConfig, PipelineError, StageReport
-from repro.compat import needs_argsort_gather_workaround
 from repro.core.operator import CooOperator, LinearOperator, ShardedCooOperator
 from repro.core.reduce import (
     CoarsenConfig,
@@ -68,6 +67,7 @@ from repro.kernels.lsh_candidates.ops import (
 from repro.core.similarity import build_knn_graph, graph_from_knn
 from repro.sparse.distributed import (
     ShardedCOO,
+    auto_mesh,
     global_rows,
     normalize_sharded,
     partition_coo_by_rows,
@@ -304,6 +304,7 @@ class Plan:
         # ShardedCooOperator raises); construction stays mesh-free so plans
         # round-trip through to_dict()/from_dict() and get the mesh
         # reattached afterwards.
+        object.__setattr__(self, "mesh", auto_mesh(self.mesh))
         if self.gather_dtype is not None:
             # canonicalize to the dtype name so configs stay JSON-safe and
             # round-trip equal (astype accepts the string form)
@@ -640,11 +641,8 @@ class SpectralPipeline:
             from repro.core.distributed_pipeline import make_knn_rowblock
 
             p = x if points is None else points
-            n = p.shape[0]
             axis = self.plan.axis
             axis = axis if isinstance(axis, str) else axis[0]
-            n_shards = self.plan.mesh.shape[axis]
-            assert n % n_shards == 0, (n, n_shards)
             knn = make_knn_rowblock(
                 self.plan.mesh, g.knn_k, axis=axis,
                 block_q=g.block_q or 1024, impl=g.impl, interpret=g.interpret,
@@ -652,19 +650,6 @@ class SpectralPipeline:
                 candidates=g.candidates, lsh_seed=g.lsh_seed,
                 exchange=self.plan.stage1_exchange)
             dist2, idx = knn(p)
-            if needs_argsort_gather_workaround():
-                # Re-replicate the small [n, k] search results before graph
-                # assembly: the O(n²d) work was the sharded part; assembly is
-                # O(nk) and the argsort gather miscompiles under GSPMD on
-                # operands left partially replicated over the unmentioned
-                # mesh axes (psum-doubling, jax 0.4.x CPU — ROADMAP: "Revisit
-                # the GSPMD argsort-gather miscompile").  Gated on the jax
-                # version so bumping the pin drops the extra all-gather.
-                from jax.sharding import NamedSharding, PartitionSpec as P
-
-                rep = NamedSharding(self.plan.mesh, P())
-                dist2 = jax.lax.with_sharding_constraint(dist2, rep)
-                idx = jax.lax.with_sharding_constraint(idx, rep)
             w = graph_from_knn(x, dist2, idx, measure=g.measure, sigma=g.sigma,
                                eps=g.eps, dist2_in_x_space=points is None)
             return self.prepare(w)
@@ -735,31 +720,44 @@ class SpectralPipeline:
             kmeans_iterations=res.iterations,
         )
 
-    def _kmeans_sharded_dispatch(self, n: int, kcfg: KMeansConfig) -> bool:
-        """True iff Stage 3 routes to the shard_map ``kmeans_sharded`` loop.
-        The reseed rung is available there too: ``empty="reseed_farthest"``
-        adds a second packed psum of per-shard farthest-point candidates
-        (it only needs n//S >= k rows per shard)."""
+    def _kmeans_sharded_dispatch(self, n: int, d: int,
+                                 kcfg: KMeansConfig) -> bool:
+        """True iff Stage 3 routes to the shard_map ``kmeans_sharded`` loop:
+        under the shard_map plan, and under any sharded plan whose Stage 3
+        runs a Mosaic kernel (GSPMD cannot partition one).  The reseed rung
+        is available there too: ``empty="reseed_farthest"`` adds a second
+        packed psum of per-shard farthest-point candidates (it only needs
+        k rows per shard)."""
         plan = self.plan
-        if not (plan.device == "sharded" and plan.variant == "shard_map"
-                and kcfg.iter == "fused" and plan.mesh is not None):
-            return False
-        import math as _math
-
-        axes = (plan.axis,) if isinstance(plan.axis, str) else tuple(plan.axis)
-        axis_size = _math.prod(plan.mesh.shape[a] for a in axes)
-        return n % axis_size == 0
+        return (plan.device == "sharded" and kcfg.iter == "fused"
+                and plan.mesh is not None
+                and (plan.variant == "shard_map"
+                     or km.runs_mosaic(n, d, kcfg)))
 
     def _run_kmeans(self, h: Array, kcfg: KMeansConfig, key: Array):
-        # Plan dispatch: the shard_map plan gets the explicit one-psum-per-
-        # iteration Lloyd loop (fused iteration only — the two-pass modes
-        # stay on the GSPMD formulation, as do row counts that don't tile
-        # the mesh axis).
-        if self._kmeans_sharded_dispatch(h.shape[0], kcfg):
+        # Plan dispatch: the shard_map plan, and a sharded plan whose Stage 3
+        # runs Mosaic, get the explicit one-psum-per-iteration Lloyd loop
+        # (fused iteration only — the two-pass modes stay on the GSPMD
+        # formulation, where a Mosaic assign kernel cannot go).
+        if self._kmeans_sharded_dispatch(*h.shape, kcfg):
             from repro.core.distributed_pipeline import kmeans_sharded
 
+            if self.plan.variant == "gspmd":
+                # GSPMD runs this plan's Stage 2 replicated; pin the
+                # embedding there, or Stage 3's row blocks propagate back
+                # into the Lanczos reductions and reorder their sums
+                from jax.sharding import NamedSharding, PartitionSpec as P
+
+                h = jax.lax.with_sharding_constraint(
+                    h, NamedSharding(self.plan.mesh, P()))
             return kmeans_sharded(h, kcfg, key, mesh=self.plan.mesh,
                                   axis=self.plan.axis)
+        if (self.plan.device == "sharded" and self.plan.mesh is not None
+                and km.runs_mosaic(h.shape[0], h.shape[1], kcfg)):
+            raise ValueError(
+                f"KMeansConfig(iter={kcfg.iter!r}) runs a Mosaic assign "
+                "kernel, which GSPMD cannot partition over the sharded plan's "
+                "mesh: use iter='fused' (kmeans_sharded) or assign='ref'")
         return km.kmeans(h, kcfg, key)
 
     # -- the stage DAG ------------------------------------------------------
@@ -1006,15 +1004,16 @@ class SpectralPipeline:
             # points.  Unavailable only when the config already reseeds
             # (the shard_map path reseeds too, via its second packed psum
             # of per-shard farthest candidates — needs k rows per shard).
-            n_rows = st.embedding.embedding.shape[0]
+            n_rows, width = st.embedding.embedding.shape
             can_reseed = kcfg.empty == "keep"
-            if can_reseed and self._kmeans_sharded_dispatch(n_rows, kcfg):
+            if can_reseed and self._kmeans_sharded_dispatch(n_rows, width,
+                                                             kcfg):
                 import math as _math
 
                 axes = (self.plan.axis,) if isinstance(self.plan.axis, str) \
                     else tuple(self.plan.axis)
                 shards = _math.prod(self.plan.mesh.shape[a] for a in axes)
-                can_reseed = n_rows // shards >= kcfg.k
+                can_reseed = -(-n_rows // shards) >= kcfg.k  # padded rows
             if (empty > 0 or bad) and attempts < hc.max_attempts \
                     and can_reseed:
                 rungs.append(f"kmeans_reseed_farthest[empty={empty}]")

@@ -26,3 +26,16 @@ def pad_to(a: jax.Array, size: int, axis: int, value=0.0):
 
 def round_up(x: int, mult: int) -> int:
     return ((x + mult - 1) // mult) * mult
+
+
+def ell_use_pallas(name: str, refusal: str, impl: str,
+                   interpret: bool | None) -> bool:
+    """Whether an ELL wrapper runs its Pallas body.  Mosaic refuses the ELL
+    kernels' in-kernel gather (``refusal`` is what it says), so the body runs
+    only in interpret mode; ``impl="pallas"`` where it would have to compile
+    for the TPU raises with the compiler's reason."""
+    if impl == "pallas" and not interpret and jax.default_backend() == "tpu":
+        raise NotImplementedError(
+            f"the {name} Pallas kernel does not compile for TPU: {refusal}; "
+            f"use impl='auto' (XLA path)")
+    return impl == "pallas" or (impl == "auto" and bool(interpret))

@@ -4,6 +4,12 @@
 block-Lanczos eigensolver.  The Pallas kernel covers the ELL body; the COO
 overflow tail (heavy-degree rows beyond the ELL width) goes through the
 segment-sum SpMM and is added in.
+
+The kernel does not compile for a TPU: Mosaic refuses its in-kernel gather
+``jnp.take(x, cols)`` (:data:`MOSAIC_REFUSAL`).  So ``impl="auto"`` runs the
+XLA path (``ell_spmm_ref``) on every backend, ``impl="pallas"`` on a TPU
+raises with the compiler's reason, and the kernel body runs only in
+interpret mode (tests) until it is rewritten without the gather.
 """
 from __future__ import annotations
 
@@ -12,10 +18,15 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.kernels._util import ell_use_pallas
 from repro.kernels.ell_spmm.kernel import ell_spmm_cheb_pallas, ell_spmm_pallas
 from repro.kernels.ell_spmm.ref import ell_spmm_cheb_ref, ell_spmm_ref
 from repro.sparse.formats import BlockELL
 from repro.sparse.ops import spmm_coo
+
+# What the TPU compiler (jax 0.9.0, v5e) says about the in-kernel gather.
+MOSAIC_REFUSAL = ("ValueError: Shape mismatch in input, indices and output "
+                  "(the [n, b] gather jnp.take(x, cols, axis=0))")
 
 
 @partial(jax.jit, static_argnames=("impl", "interpret", "block_rows"))
@@ -33,17 +44,14 @@ def ell_spmm(
     cols2d = m.cols.reshape(n_rows_padded, w)
     vals2d = m.vals.reshape(n_rows_padded, w)
 
-    on_tpu = jax.default_backend() == "tpu"
-    if impl == "ref" or (impl == "auto" and not on_tpu and not interpret):
+    if not ell_use_pallas("ell_spmm", MOSAIC_REFUSAL, impl, interpret):
         body = ell_spmm_ref(x, cols2d, vals2d)
     else:
-        if interpret is None:
-            interpret = not on_tpu
         blk = block_rows
         while n_rows_padded % blk:
             blk //= 2
         body = ell_spmm_pallas(
-            x.astype(jnp.float32), cols2d, vals2d, block_rows=max(blk, 1), interpret=interpret
+            x.astype(jnp.float32), cols2d, vals2d, block_rows=max(blk, 1), interpret=True
         )
     y = body[: m.shape[0]]
     y = y + spmm_coo(m.tail, x).astype(jnp.float32)
@@ -83,12 +91,9 @@ def ell_spmm_cheb_step(
     xp = jnp.pad(x.astype(jnp.float32), pad)
     pp = jnp.pad(prev.astype(jnp.float32), pad)
 
-    on_tpu = jax.default_backend() == "tpu"
-    if impl == "ref" or (impl == "auto" and not on_tpu and not interpret):
+    if not ell_use_pallas("ell_spmm", MOSAIC_REFUSAL, impl, interpret):
         body = ell_spmm_cheb_ref(xp, cols2d, vals2d, pp, ca, cb)
     else:
-        if interpret is None:
-            interpret = not on_tpu
         blk = block_rows
         while n_rows_padded % blk:
             blk //= 2
@@ -99,7 +104,7 @@ def ell_spmm_cheb_step(
             pp,
             jnp.stack([ca, cb]).reshape(1, 2),
             block_rows=max(blk, 1),
-            interpret=interpret,
+            interpret=True,
         )
     y = body[:n]
     y = y + ca * spmm_coo(m.tail, x).astype(jnp.float32)

@@ -45,14 +45,17 @@ def _kernel(c_norm_ref, x_ref, c_ref, min_ref, idx_ref, *, block_k: int):
     x = x_ref[...]  # [bq, d]
     c = c_ref[...]  # [bk, d]
     # S_tile = ‖c‖² − 2 x·cᵀ   (row-constant ‖x‖² added by the wrapper)
-    s = c_norm_ref[...][None, :] - 2.0 * jax.lax.dot_general(
+    s = c_norm_ref[...] - 2.0 * jax.lax.dot_general(
         x,
         c,
         dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )  # [bq, bk]
-    tile_min = jnp.min(s, axis=1)
-    tile_arg = jnp.argmin(s, axis=1).astype(jnp.int32) + j * block_k
+    # per-row results go lane-dense ([1, bq]) like the output blocks
+    tile_min = jnp.min(s, axis=1).reshape(1, -1)
+    tile_arg = (jnp.argmin(s, axis=1).astype(jnp.int32)
+                + j * block_k).reshape(1, -1)
     better = tile_min < min_ref[...]
     idx_ref[...] = jnp.where(better, tile_arg, idx_ref[...])
     min_ref[...] = jnp.where(better, tile_min, min_ref[...])
@@ -61,12 +64,14 @@ def _kernel(c_norm_ref, x_ref, c_ref, min_ref, idx_ref, *, block_k: int):
 def kmeans_assign_pallas(
     x: jax.Array,  # [n, d] (n % block_q == 0, d % 128 == 0)
     c: jax.Array,  # [k, d] (k % block_k == 0)
-    c_norm: jax.Array,  # [k]
+    c_norm: jax.Array,  # [1, k]
     *,
     block_q: int = KMEANS_BLOCK_Q,
     block_k: int = KMEANS_BLOCK_K,
     interpret: bool = False,
 ):
+    """Raw kernel entry: returns (min [1, n] without the ‖x‖² row term,
+    idx [1, n] int32) — per-row vectors are lane-dense 2-D blocks."""
     n, d = x.shape
     k = c.shape[0]
     assert n % block_q == 0 and k % block_k == 0, (n, k, block_q, block_k)
@@ -75,17 +80,17 @@ def kmeans_assign_pallas(
         functools.partial(_kernel, block_k=block_k),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_k,), lambda i, j: (j,)),  # c_norm tile
+            pl.BlockSpec((1, block_k), lambda i, j: (0, j)),  # c_norm tile
             pl.BlockSpec((block_q, d), lambda i, j: (i, 0)),  # x tile
             pl.BlockSpec((block_k, d), lambda i, j: (j, 0)),  # c tile
         ],
         out_specs=[
-            pl.BlockSpec((block_q,), lambda i, j: (i,)),  # running min
-            pl.BlockSpec((block_q,), lambda i, j: (i,)),  # running argmin
+            pl.BlockSpec((1, block_q), lambda i, j: (0, i)),  # running min
+            pl.BlockSpec((1, block_q), lambda i, j: (0, i)),  # running argmin
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((n,), jnp.float32),
-            jax.ShapeDtypeStruct((n,), jnp.int32),
+            jax.ShapeDtypeStruct((1, n), jnp.float32),
+            jax.ShapeDtypeStruct((1, n), jnp.int32),
         ],
         interpret=interpret,
     )(c_norm, x, c)

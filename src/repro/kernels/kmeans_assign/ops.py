@@ -62,8 +62,9 @@ def kmeans_assign(
         cn = cn.at[k:].set(jnp.inf)
 
     tile_min, labels = kmeans_assign_pallas(
-        xf, cf, cn, block_q=bq, block_k=bk, interpret=interpret
+        xf, cf, cn[None, :], block_q=bq, block_k=bk, interpret=interpret
     )
+    tile_min, labels = tile_min[0], labels[0]
     xn = (x.astype(jnp.float32) ** 2).sum(1) if x_norm is None else x_norm.astype(jnp.float32)
     dist2 = jnp.maximum(tile_min[:n] + xn, 0.0)
     return labels[:n], dist2

@@ -34,8 +34,12 @@ VMEM working set per step: x tile (block_q·d_aug) + c tile (block_k·d_aug)
 + S tile (block_q·block_k) + one-hot chunk (block_q·block_k, transient —
 the accumulate contraction is k-chunked so the accumulator is the only
 full-k object) + acc (k_pad·d_aug), all fp32.  The wrapper models this sum
-against a 12 MB budget (v5e core = 16 MB) and raises unavailability past
-it; the (8, 128) fp32 tiling constraint fixes the padding multiples.
+against a 12 MB budget and takes the chunked path past it; the (8, 128) fp32
+tiling constraint fixes the padding multiples.  Mosaic needs more than the
+model: double-buffered input tiles, and the bf16 splits of the fp32 operands
+that ``Precision.HIGHEST`` multiplies (21.3 MB at the budget's edge, k=1024,
+d=639).  So the kernel raises its scoped-VMEM limit past v5e's 16 MB default
+to :data:`VMEM_LIMIT_BYTES` (a v5e core has 128 MiB).
 """
 from __future__ import annotations
 
@@ -44,8 +48,11 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels._util import KMEANS_BLOCK_K, KMEANS_BLOCK_Q
+
+VMEM_LIMIT_BYTES = 32 << 20
 
 
 def _kernel(c_norm_ref, x_ref, c_ref, min_ref, idx_ref, acc_ref, *,
@@ -66,46 +73,52 @@ def _kernel(c_norm_ref, x_ref, c_ref, min_ref, idx_ref, acc_ref, *,
     x = x_ref[...]  # [bq, d_aug] (column d of the unpadded layout is ones)
     c = c_ref[...]  # [bk, d_aug] (zero in the ones-column => distances exact)
     # S_tile = ‖c‖² − 2 x·cᵀ   (row-constant ‖x‖² added by the wrapper)
-    s = c_norm_ref[...][None, :] - 2.0 * jax.lax.dot_general(
+    s = c_norm_ref[...] - 2.0 * jax.lax.dot_general(
         x,
         c,
         dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )  # [bq, bk]
-    tile_min = jnp.min(s, axis=1)
-    tile_arg = jnp.argmin(s, axis=1).astype(jnp.int32) + j * block_k
+    # per-row results go lane-dense ([1, bq]) like the output blocks
+    tile_min = jnp.min(s, axis=1).reshape(1, -1)
+    tile_arg = (jnp.argmin(s, axis=1).astype(jnp.int32)
+                + j * block_k).reshape(1, -1)
     better = tile_min < min_ref[...]
-    new_idx = jnp.where(better, tile_arg, idx_ref[...])
+    new_idx = jnp.where(better, tile_arg, idx_ref[...])  # [1, bq]
     idx_ref[...] = new_idx
     min_ref[...] = jnp.where(better, tile_min, min_ref[...])
 
     @pl.when(j == nk - 1)
     def _accumulate():  # labels for this query tile are now final
-        # k-chunked one-hot contraction: the transient is [bq, block_k], not
-        # [bq, k_pad] — the accumulator stays the only full-k VMEM object
+        # k-chunked one-hot contraction: the transient is [block_k, bq], not
+        # [k_pad, bq] — the accumulator stays the only full-k VMEM object
         for kc in range(k_pad // block_k):
-            lanes = kc * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (x.shape[0], block_k), 1)
-            onehot = (new_idx[:, None] == lanes).astype(jnp.float32)
+            rows = kc * block_k + jax.lax.broadcasted_iota(
+                jnp.int32, (block_k, x.shape[0]), 0)
+            onehot_t = (rows == new_idx).astype(jnp.float32)  # [block_k, bq]
             acc_ref[kc * block_k:(kc + 1) * block_k, :] += jax.lax.dot_general(
-                onehot,
+                onehot_t,
                 x,
-                dimension_numbers=(((0,), (0,)), ((), ())),
+                dimension_numbers=(((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
+                precision=jax.lax.Precision.HIGHEST,
             )  # [block_k, d_aug] — padded x rows are all-zero, add nothing
 
 
 def kmeans_iter_pallas(
     x: jax.Array,  # [n_p, d_aug] (n_p % block_q == 0, d_aug % 128 == 0)
     c: jax.Array,  # [k_p, d_aug] (k_p % block_k == 0, zero ones-column)
-    c_norm: jax.Array,  # [k_p] with +inf on padded centroids
+    c_norm: jax.Array,  # [1, k_p] with +inf on padded centroids
     *,
     block_q: int = KMEANS_BLOCK_Q,
     block_k: int = KMEANS_BLOCK_K,
     interpret: bool = False,
 ):
-    """Raw kernel entry: returns (min [n_p] without the ‖x‖² row term,
-    idx [n_p] int32, acc [k_p, d_aug] fp32)."""
+    """Raw kernel entry: returns (min [1, n_p] without the ‖x‖² row term,
+    idx [1, n_p] int32, acc [k_p, d_aug] fp32).  Per-row vectors are 2-D
+    [1, n] so their blocks tile lanes; a 1-D block must match the tiling
+    XLA picks for the whole array, which Mosaic refuses for most sizes."""
     n, d_aug = x.shape
     k_p = c.shape[0]
     assert n % block_q == 0 and k_p % block_k == 0, (n, k_p, block_q, block_k)
@@ -114,19 +127,20 @@ def kmeans_iter_pallas(
         functools.partial(_kernel, block_k=block_k, k_pad=k_p),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_k,), lambda i, j: (j,)),  # ‖c‖² tile
+            pl.BlockSpec((1, block_k), lambda i, j: (0, j)),  # ‖c‖² tile
             pl.BlockSpec((block_q, d_aug), lambda i, j: (i, 0)),  # x tile
             pl.BlockSpec((block_k, d_aug), lambda i, j: (j, 0)),  # c tile
         ],
         out_specs=[
-            pl.BlockSpec((block_q,), lambda i, j: (i,)),  # running min
-            pl.BlockSpec((block_q,), lambda i, j: (i,)),  # running argmin
+            pl.BlockSpec((1, block_q), lambda i, j: (0, i)),  # running min
+            pl.BlockSpec((1, block_q), lambda i, j: (0, i)),  # running argmin
             pl.BlockSpec((k_p, d_aug), lambda i, j: (0, 0)),  # resident acc
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((n,), jnp.float32),
-            jax.ShapeDtypeStruct((n,), jnp.int32),
+            jax.ShapeDtypeStruct((1, n), jnp.float32),
+            jax.ShapeDtypeStruct((1, n), jnp.int32),
             jax.ShapeDtypeStruct((k_p, d_aug), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(c_norm, x, c)

@@ -6,8 +6,7 @@ paths, picked by ``impl``:
 
 * ``pallas`` — the TPU kernel (:mod:`.kernel`): online argmin + resident
   accumulator, counts folded into an augmented ones-column.  Raises
-  ``NotImplementedError`` when the ``[k_pad, d_aug]`` accumulator would not
-  fit the VMEM budget;
+  ``NotImplementedError`` when asked for explicitly past the VMEM budget;
 * ``chunked`` — the online jnp formulation for non-TPU backends: a
   ``lax.scan`` over row blocks carrying running (sums‖counts) and emitting
   per-block (labels, dmin).  Only a ``[block_q, k]`` distance tile is ever
@@ -20,7 +19,8 @@ paths, picked by ``impl``:
 
 ``auto`` = pallas on TPU (chunked if the accumulator exceeds VMEM),
 pallas-interpret when ``interpret`` is set (kernel validation on CPU),
-chunked otherwise.
+chunked otherwise.  :func:`kmeans_iter_engine` makes that choice from the
+shapes before the call, so a caller can name the engine it got.
 """
 from __future__ import annotations
 
@@ -40,7 +40,7 @@ from repro.kernels.kmeans_iter.ref import kmeans_iter_ref
 
 # Modeled per-step VMEM working set budget for the Pallas path (resident
 # accumulator + streamed tiles; a v5e core has 16 MB).  Past this, `auto`
-# falls back to the chunked online path, which is accumulator-unbounded.
+# chooses the chunked online path, which is accumulator-unbounded.
 ACC_VMEM_BUDGET_BYTES = 12 << 20
 
 
@@ -88,16 +88,53 @@ def _chunked(x, c, x_norm, block_q: int):
     return labels.reshape(-1)[:n], dmin.reshape(-1)[:n], acc[:, :d], acc[:, d]
 
 
+def _tiles(n: int, d: int, k: int, block_q: int, block_k: int):
+    """The Pallas path's (bq, bk, n_p, k_p, d_aug) for these shapes."""
+    bq = min(block_q, _round_up(n, 8))
+    bk = min(block_k, _round_up(k, 128))
+    d_aug = _round_up(d + 1, 128)  # one pad column repurposed as the counter
+    return bq, bk, _round_up(n, bq), _round_up(k, bk), d_aug
+
+
+def pallas_workset_bytes(n: int, d: int, k: int, *,
+                         block_q: int = KMEANS_BLOCK_Q,
+                         block_k: int = KMEANS_BLOCK_K) -> int:
+    """Modeled VMEM working set of the Pallas path: resident acc + S tile +
+    one-hot chunk + x/c tiles (kernel.py header), fp32."""
+    bq, bk, _, k_p, d_aug = _tiles(n, d, k, block_q, block_k)
+    return 4 * (k_p * d_aug + 2 * bq * bk + (bq + bk) * d_aug)
+
+
+def kmeans_iter_engine(n: int, d: int, k: int, *, impl: str = "auto",
+                       interpret: bool | None = None,
+                       block_q: int = KMEANS_BLOCK_Q,
+                       block_k: int = KMEANS_BLOCK_K) -> str:
+    """The engine :func:`kmeans_iter` runs for ``x: [n, d]`` and ``k``
+    centroids: ``"pallas"`` (compiled for the TPU), ``"pallas-interpret"``,
+    ``"chunked"`` or ``"ref"``.  Decided from the
+    shapes and the backend alone, before any kernel is traced, so callers can
+    report it; ``auto`` takes the chunked path where the Pallas accumulator
+    would exceed :data:`ACC_VMEM_BUDGET_BYTES`."""
+    if impl in ("ref", "chunked"):
+        return impl
+    on_tpu = jax.default_backend() == "tpu"
+    if interpret is None:
+        interpret = not on_tpu if impl == "pallas" else False
+    pallas = "pallas-interpret" if interpret else "pallas"
+    if impl == "pallas":
+        return pallas
+    if not on_tpu and not interpret:
+        return "chunked"
+    over = pallas_workset_bytes(n, d, k, block_q=block_q,
+                                block_k=block_k) > ACC_VMEM_BUDGET_BYTES
+    return "chunked" if over else pallas
+
+
 def _pallas(x, c, x_norm, block_q: int, block_k: int, interpret: bool):
     n, d = x.shape
     k = c.shape[0]
-    bq = min(block_q, _round_up(n, 8))
-    bk = min(block_k, _round_up(k, 128))
-    n_p = _round_up(n, bq)
-    k_p = _round_up(k, bk)
-    d_aug = _round_up(d + 1, 128)  # one pad column repurposed as the counter
-    # resident acc + S tile + one-hot chunk + x/c tiles (kernel.py header)
-    workset = 4 * (k_p * d_aug + 2 * bq * bk + (bq + bk) * d_aug)
+    bq, bk, n_p, k_p, d_aug = _tiles(n, d, k, block_q, block_k)
+    workset = pallas_workset_bytes(n, d, k, block_q=block_q, block_k=block_k)
     if workset > ACC_VMEM_BUDGET_BYTES:
         raise NotImplementedError(
             f"kmeans_iter modeled working set {workset >> 20} MB "
@@ -115,11 +152,11 @@ def _pallas(x, c, x_norm, block_q: int, block_k: int, interpret: bool):
         cn = cn.at[k:].set(jnp.inf)
 
     tile_min, labels, acc = kmeans_iter_pallas(
-        xf, cf, cn, block_q=bq, block_k=bk, interpret=interpret
+        xf, cf, cn[None, :], block_q=bq, block_k=bk, interpret=interpret
     )
     xn = (x.astype(jnp.float32) ** 2).sum(1) if x_norm is None else x_norm.astype(jnp.float32)
-    dmin = jnp.maximum(tile_min[:n] + xn, 0.0)
-    return labels[:n], dmin, acc[:k, :d], acc[:k, d]
+    dmin = jnp.maximum(tile_min[0, :n] + xn, 0.0)
+    return labels[0, :n], dmin, acc[:k, :d], acc[:k, d]
 
 
 @partial(jax.jit, static_argnames=("block_q", "block_k", "impl", "interpret"))
@@ -135,17 +172,15 @@ def kmeans_iter(
 ):
     """labels[i], dist²[i], per-cluster sums [k, d] and counts [k] — one
     Lloyd iteration from one pass over ``x``.  Empty-cluster policy is the
-    caller's (counts==0 rows carry zero sums)."""
-    if impl == "ref":
+    caller's (counts==0 rows carry zero sums).  The engine is
+    :func:`kmeans_iter_engine` of the shapes; ``impl="pallas"`` past the
+    VMEM budget raises ``NotImplementedError``."""
+    engine = kmeans_iter_engine(x.shape[0], x.shape[1], c.shape[0], impl=impl,
+                                interpret=interpret, block_q=block_q,
+                                block_k=block_k)
+    if engine == "ref":
         return kmeans_iter_ref(x, c, x_norm)
-    on_tpu = jax.default_backend() == "tpu"
-    if impl == "chunked" or (impl == "auto" and not on_tpu and not interpret):
+    if engine == "chunked":
         return _chunked(x, c, x_norm, block_q)
-    if interpret is None:
-        interpret = not on_tpu
-    try:
-        return _pallas(x, c, x_norm, block_q, block_k, interpret)
-    except NotImplementedError:
-        if impl == "pallas":
-            raise
-        return _chunked(x, c, x_norm, block_q)
+    return _pallas(x, c, x_norm, block_q, block_k,
+                   interpret=engine == "pallas-interpret")

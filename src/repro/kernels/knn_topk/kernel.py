@@ -59,11 +59,12 @@ def _kernel(off_ref, cn_ref, xq_ref, xc_ref, dist_ref, idx_ref, *, block_q: int,
     xq = xq_ref[...]  # [bq, d]
     xc = xc_ref[...]  # [bk, d]
     # S_tile = ‖c‖² − 2 x·cᵀ   (row-constant ‖x‖² added by the wrapper)
-    s = cn_ref[...][None, :] - 2.0 * jax.lax.dot_general(
+    s = cn_ref[...] - 2.0 * jax.lax.dot_general(
         xq,
         xc,
         dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )  # [bq, bk]
     rows_g = (off_ref[0, 0] + i * block_q
               + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0))
@@ -73,15 +74,20 @@ def _kernel(off_ref, cn_ref, xq_ref, xc_ref, dist_ref, idx_ref, *, block_q: int,
     # Merge the candidate tile into the running top-k: k_pad min-extract-mask
     # passes over the concatenation.  Ascending extraction order keeps the
     # running buffer sorted; ties resolve to the earliest slot, which prefers
-    # already-kept entries (stable across tiles).
+    # already-kept entries (stable across tiles), so the result is the k
+    # smallest in (dist, id) order.  The earliest slot is taken explicitly:
+    # Mosaic's argmin does not promise the first of several equal minima.
     merged_d = jnp.concatenate([dist_ref[...], s], axis=1)  # [bq, k_pad+bk]
     merged_i = jnp.concatenate([idx_ref[...], cols_g], axis=1)
     lane = jax.lax.broadcasted_iota(jnp.int32, merged_d.shape, 1)
+    n_lanes = merged_d.shape[1]
     out_d, out_i = [], []
     for _ in range(k_pad):
-        am = jnp.argmin(merged_d, axis=1).astype(jnp.int32)  # [bq]
-        hit = lane == am[:, None]
-        out_d.append(jnp.min(merged_d, axis=1))
+        mn = jnp.min(merged_d, axis=1, keepdims=True)  # [bq, 1]
+        am = jnp.min(jnp.where(merged_d == mn, lane, n_lanes), axis=1,
+                     keepdims=True)
+        hit = lane == am
+        out_d.append(mn[:, 0])
         out_i.append(jnp.where(hit, merged_i, 0).sum(axis=1))  # one hit per row
         merged_d = jnp.where(hit, jnp.inf, merged_d)
     dist_ref[...] = jnp.stack(out_d, axis=1)
@@ -91,7 +97,7 @@ def _kernel(off_ref, cn_ref, xq_ref, xc_ref, dist_ref, idx_ref, *, block_q: int,
 def knn_topk_pallas(
     xq: jax.Array,  # [nq_p, d] padded queries
     xc: jax.Array,  # [nc_p, d] padded candidates
-    c_norm: jax.Array,  # [nc_p] ‖c‖² with +inf on padded rows
+    c_norm: jax.Array,  # [1, nc_p] ‖c‖² with +inf on padded rows
     k_pad: int,
     *,
     query_offset: jax.Array | int = 0,  # global row id of xq[0]
@@ -112,7 +118,7 @@ def knn_topk_pallas(
         in_specs=[
             pl.BlockSpec((1, 1), lambda i, j: (0, 0),
                          memory_space=pltpu.SMEM),  # global query-row offset
-            pl.BlockSpec((block_k,), lambda i, j: (j,)),  # ‖c‖² tile
+            pl.BlockSpec((1, block_k), lambda i, j: (0, j)),  # ‖c‖² tile
             pl.BlockSpec((block_q, d), lambda i, j: (i, 0)),  # query tile
             pl.BlockSpec((block_k, d), lambda i, j: (j, 0)),  # candidate tile
         ],

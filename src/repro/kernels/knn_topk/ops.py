@@ -23,6 +23,30 @@ from repro.kernels.knn_topk.kernel import knn_topk_pallas
 from repro.kernels.knn_topk.ref import knn_topk_ref
 
 
+# The kernel's merge unrolls k_pad min-extract passes, and Mosaic keeps one
+# [block_q, k_pad + block_k] fp32 tile live per pass.  Rows per query block
+# are capped so those tiles stay within this budget, under v5e's 16 MB
+# default scoped-VMEM limit (at k=64, block_q=256 the compiler asks 24 MB).
+MERGE_VMEM_BUDGET_BYTES = 8 << 20
+
+
+def _merge_rows(k_pad: int, block_k: int) -> int:
+    """Largest multiple of 8 query rows whose merge tiles fit the budget."""
+    rows = MERGE_VMEM_BUDGET_BYTES // (4 * k_pad * (k_pad + block_k))
+    return max(8, rows // 8 * 8)
+
+
+def knn_topk_engine(impl: str = "auto", interpret: bool | None = None) -> str:
+    """The engine :func:`knn_topk` runs: ``"pallas"`` (compiled for the
+    TPU), ``"pallas-interpret"`` or ``"ref"`` (the jnp reference, which
+    ``auto`` picks off TPU unless ``interpret`` asks for the kernel)."""
+    on_tpu = jax.default_backend() == "tpu"
+    if impl == "ref" or (impl == "auto" and not on_tpu and not interpret):
+        return "ref"
+    interpret = (not on_tpu) if interpret is None else interpret
+    return "pallas-interpret" if interpret else "pallas"
+
+
 @partial(jax.jit, static_argnames=("k", "block_q", "block_k", "impl", "interpret"))
 def knn_topk(
     x: jax.Array,  # [n, d] candidate points
@@ -50,28 +74,27 @@ def knn_topk(
     """
     n, d = x.shape
     assert k >= 1, k
-    on_tpu = jax.default_backend() == "tpu"
-    if impl == "ref" or (impl == "auto" and not on_tpu and not interpret):
+    engine = knn_topk_engine(impl, interpret)
+    if engine == "ref":
         dist, idx = knn_topk_ref(x, k, queries=queries,
                                  query_offset=query_offset)
     else:
-        if interpret is None:
-            interpret = not on_tpu
+        interpret = engine == "pallas-interpret"
         q = x if queries is None else queries
         nq = q.shape[0]
         bk = min(block_k, _round_up(n, 128))
-        bq = min(block_q, _round_up(nq, 8))
+        k_pad = _round_up(k, 8)
+        bq = min(block_q, _round_up(nq, 8), _merge_rows(k_pad, bk))
         nq_p = _round_up(nq, bq)
         nc_p = _round_up(n, bk)
         d_p = _round_up(d, 128)
-        k_pad = _round_up(k, 8)
 
         xf = _pad_to(_pad_to(x.astype(jnp.float32), nc_p, 0), d_p, 1)
         qf = _pad_to(_pad_to(q.astype(jnp.float32), nq_p, 0), d_p, 1)
         cn = (xf * xf).sum(1)
         if nc_p > n:  # padded candidates must never enter the top-k
             cn = cn.at[n:].set(jnp.inf)
-        raw, idx = knn_topk_pallas(qf, xf, cn, k_pad,
+        raw, idx = knn_topk_pallas(qf, xf, cn[None, :], k_pad,
                                    query_offset=query_offset,
                                    block_q=bq, block_k=bk, interpret=interpret)
         raw, idx = raw[:nq, :k], idx[:nq, :k]

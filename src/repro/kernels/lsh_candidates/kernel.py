@@ -37,24 +37,28 @@ def _kernel(pows_ref, x_ref, planes_ref, codes_ref, tie_ref, *, n_bits: int):
         x, pl_t,
         dimension_numbers=(((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )  # [block_n, B_pad]
     bits = (proj >= 0.0).astype(jnp.int32)
     # pows carries 2^j at columns j < n_bits and 0 elsewhere (incl. the
     # tie-break column), so padded/tie columns never enter the code.
-    codes_ref[...] = (bits * pows_ref[...][None, :]).sum(axis=1)[None, :]
-    tie_ref[...] = proj[:, n_bits][None, :]
+    codes_ref[...] = (bits * pows_ref[...]).sum(axis=1).reshape(codes_ref.shape)
+    tie_ref[...] = proj[:, n_bits].reshape(tie_ref.shape)
 
 
 def hash_codes_pallas(
     x: jax.Array,  # [n_pad, d_pad] padded points
     planes: jax.Array,  # [T, d_pad, B_pad] padded plane blocks
-    pows: jax.Array,  # [B_pad] int32 packing weights (0 beyond n_bits)
+    pows: jax.Array,  # [1, B_pad] int32 packing weights (0 beyond n_bits)
     n_bits: int,
     *,
     block_n: int = 256,
     interpret: bool = False,
 ):
-    """Raw kernel entry: returns (codes [T, n_pad] int32, tie [T, n_pad] f32)."""
+    """Raw kernel entry: returns (codes [T, 1, n_pad] int32, tie [T, 1, n_pad]
+    f32).  The unit middle axis lets a (1, 1, block_n) block tile lanes for
+    any table count T (a (1, block_n) block over [T, n] needs T == 1 or
+    8 | 1)."""
     n, d = x.shape
     t, dp, bp = planes.shape
     assert n % block_n == 0 and d == dp, (x.shape, planes.shape, block_n)
@@ -64,17 +68,17 @@ def hash_codes_pallas(
         functools.partial(_kernel, n_bits=n_bits),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bp,), lambda t, i: (0,)),  # packing weights
+            pl.BlockSpec((1, bp), lambda t, i: (0, 0)),  # packing weights
             pl.BlockSpec((block_n, d), lambda t, i: (i, 0)),  # point tile
             pl.BlockSpec((1, dp, bp), lambda t, i: (t, 0, 0)),  # table planes
         ],
         out_specs=[
-            pl.BlockSpec((1, block_n), lambda t, i: (t, i)),  # codes
-            pl.BlockSpec((1, block_n), lambda t, i: (t, i)),  # tie-break
+            pl.BlockSpec((1, 1, block_n), lambda t, i: (t, 0, i)),  # codes
+            pl.BlockSpec((1, 1, block_n), lambda t, i: (t, 0, i)),  # tie-break
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((t, n), jnp.int32),
-            jax.ShapeDtypeStruct((t, n), jnp.float32),
+            jax.ShapeDtypeStruct((t, 1, n), jnp.int32),
+            jax.ShapeDtypeStruct((t, 1, n), jnp.float32),
         ],
         interpret=interpret,
     )(pows, x, planes)

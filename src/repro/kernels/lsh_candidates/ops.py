@@ -91,9 +91,9 @@ def hash_codes(
     pf = _pad_to(_pad_to(planes.astype(jnp.float32), d_p, 1), b_p, 2)
     j = jnp.arange(b_p, dtype=jnp.int32)
     pows = jnp.where(j < n_bits, jnp.left_shift(1, jnp.minimum(j, n_bits)), 0)
-    codes, tie = hash_codes_pallas(xf, pf, pows, n_bits, block_n=bn,
+    codes, tie = hash_codes_pallas(xf, pf, pows[None, :], n_bits, block_n=bn,
                                    interpret=interpret)
-    return codes[:, :n], tie[:, :n]
+    return codes[:, 0, :n], tie[:, 0, :n]
 
 
 @partial(jax.jit, static_argnames=("m", "n_tables", "n_bits", "seed", "impl",

@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import ARCHS
+from repro.launch.cache import enable_compile_cache
 
 
 def serve_cluster(args) -> int:
@@ -136,7 +137,7 @@ def serve_cluster(args) -> int:
     return failures
 
 
-def serve_online(args) -> int:
+def serve_online(args) -> dict:
     """Online point-labelling over the :mod:`repro.serve` subsystem.
 
     Train once (full pipeline on a blob pool), build a
@@ -154,6 +155,10 @@ def serve_online(args) -> int:
     :func:`~repro.core.health.numeric_problems` on its rows, neighbors
     keep serving), ``--deadline-s`` wall budgets, exit code = failure
     count.  ``--inject-fault nan-query`` poisons every odd request.
+
+    Returns the ``serve_summary`` record it prints last (``failures``,
+    batch fill, latency percentiles, ``train_converged`` over the training
+    run's stage reports, ``train_ari_vs_served``).
     """
     import functools
     import json
@@ -273,6 +278,7 @@ def serve_online(args) -> int:
         "p50_ms": round(float(lat[len(lat) // 2]) * 1e3, 2),
         "p99_ms": round(float(lat[min(int(len(lat) * 0.99),
                                       len(lat) - 1)]) * 1e3, 2),
+        "train_converged": all(bool(r.converged) for r in result.reports),
         "train_ari_vs_served": None,
     }
     # diagnostic: re-serve the pool through OOS — labels should reproduce
@@ -283,7 +289,7 @@ def serve_online(args) -> int:
         np.asarray(pool_out.labels),
         np.asarray(result.labels)[:min(args.n, 2048)]), 4)
     print(json.dumps(summary), flush=True)
-    return failures
+    return summary
 
 
 def serve_decode(args):
@@ -312,7 +318,7 @@ def serve_decode(args):
           f"{args.tokens * B / dt:.1f} tok/s ({dt/args.tokens*1e3:.1f} ms/step)")
 
 
-def main(argv=None):
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--mode", choices=["cluster", "serve", "decode"],
                     default="cluster")
@@ -350,14 +356,20 @@ def main(argv=None):
     ap.add_argument("--batch", type=int, default=2)
     ap.add_argument("--seq", type=int, default=64)
     ap.add_argument("--tokens", type=int, default=16)
-    args = ap.parse_args(argv)
+    return ap
+
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
+    enable_compile_cache()
     if args.mode in ("cluster", "serve"):
         import sys
 
-        run = serve_cluster if args.mode == "cluster" else serve_online
+        failures = (serve_cluster(args) if args.mode == "cluster"
+                    else serve_online(args)["failures"])
         # exit code = failure count (clamped below the shell's reserved
         # range) so orchestrators see partial failure without log parsing
-        sys.exit(min(run(args), 125))
+        sys.exit(min(failures, 125))
     else:
         serve_decode(args)
 

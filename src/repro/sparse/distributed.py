@@ -31,12 +31,29 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.compat import shard_map as _shard_map
 from repro.sparse.formats import COO
 
 Array = jax.Array
+
+
+def auto_mesh(mesh):
+    """``mesh`` with every axis in ``AxisType.Auto`` mode (``None`` passes).
+
+    ``jax.make_mesh`` builds ``Explicit`` axes, which put shardings into the
+    array types: a gather from a row-sharded operand (``x[col]``, the argsort
+    gather of graph assembly) then needs an ``out_sharding`` at every call
+    site, and ``qr`` refuses row-sharded inputs.  The sharded plan relies on
+    GSPMD propagation instead, so every library entry point that takes a
+    mesh (``Plan``, the shard_map builders, ``shard_vector``/``shard_edges``)
+    passes it through here — the one place the axis mode is fixed.
+    """
+    if mesh is None or all(t == AxisType.Auto for t in mesh.axis_types):
+        return mesh
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -146,6 +163,7 @@ def make_sharded_spmv(mesh: Mesh, sm: ShardedCOO, *, axis: str | tuple = "data",
     ``gather_dtype`` optionally downcasts x for the all-gather (bf16 halves
     ICI bytes; accumulation stays fp32) — a §Perf knob.
     """
+    mesh = auto_mesh(mesh)
     axes = (axis,) if isinstance(axis, str) else tuple(axis)
     espec = P(axes)
     xspec = P(axes)
@@ -198,6 +216,7 @@ def make_sharded_spmm(mesh: Mesh, sm: ShardedCOO, *, axis: str | tuple = "data",
     cost drops b× alongside the b× nnz-stream amortization — the two wins
     the block eigensolver was built for (DESIGN.md §3-4).
     """
+    mesh = auto_mesh(mesh)
     axes = (axis,) if isinstance(axis, str) else tuple(axis)
     espec = P(axes)
     xspec = P(axes, None)
@@ -266,7 +285,8 @@ def collective_bytes(jaxpr) -> dict:
     multiplied in, so apply this to unrolled programs (the Stage-1 ring is
     unrolled) or scale externally.
     """
-    core = jax.core
+    from jax.extend import core
+
     totals: dict = {}
 
     def visit(jx) -> None:
@@ -297,11 +317,11 @@ def trace_collective_bytes(fn, *args) -> dict:
 
 
 def shard_vector(mesh: Mesh, x: Array, axis="data") -> Array:
-    return jax.device_put(x, NamedSharding(mesh, P(axis)))
+    return jax.device_put(x, NamedSharding(auto_mesh(mesh), P(axis)))
 
 
 def shard_edges(mesh: Mesh, sm: ShardedCOO, axis="data") -> ShardedCOO:
-    s = NamedSharding(mesh, P(axis))
+    s = NamedSharding(auto_mesh(mesh), P(axis))
     return dataclasses.replace(
         sm,
         row_local=jax.device_put(sm.row_local, s),

@@ -204,6 +204,35 @@ def test_sharded_kmeans_matches_single_device_and_one_allreduce_per_iter():
     """))
 
 
+def test_sharded_kmeans_pads_rows_that_do_not_tile_the_mesh():
+    # n = 253 over 4 shards: zero pad rows must leave counts, the change
+    # test, the inertia and the labels as on one device
+    print(_run("""
+        import numpy as np, jax, jax.numpy as jnp
+        from repro.core.kmeans import KMeansConfig, kmeans
+        from repro.core.distributed_pipeline import kmeans_sharded
+        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        rng = np.random.default_rng(0)
+        x = jnp.asarray(rng.normal(size=(253, 6)) + 3.0, jnp.float32)
+        key = jax.random.PRNGKey(0)
+        for empty in ("keep", "reseed_farthest"):
+            cfg = KMeansConfig(k=5, max_iters=30, empty=empty)
+            r1 = jax.jit(lambda x, k: kmeans(x, cfg, k))(x, key)
+            r2 = jax.jit(lambda x, k: kmeans_sharded(
+                x, cfg, k, mesh=mesh, axis="data"))(x, key)
+            assert r2.labels.shape == (253,)
+            np.testing.assert_array_equal(np.asarray(r1.labels),
+                                          np.asarray(r2.labels))
+            np.testing.assert_allclose(np.asarray(r1.centroids),
+                                       np.asarray(r2.centroids),
+                                       rtol=1e-5, atol=1e-6)
+            np.testing.assert_allclose(float(r1.inertia), float(r2.inertia),
+                                       rtol=1e-5)
+            assert int(r1.iterations) == int(r2.iterations)
+        print("KMEANS-SHARDED-PAD-OK")
+    """))
+
+
 def test_sharded_kmeans_reseed_matches_single_device():
     # empty="reseed_farthest" sharded: the second packed psum overlays each
     # shard's k farthest [row | dmin] candidates; the revived-centroid

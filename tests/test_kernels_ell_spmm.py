@@ -158,3 +158,39 @@ def test_block_ell_operator_cheb_step_hook():
     fused = np.asarray(op.cheb_step(X, P, ca, cb))
     generic = np.asarray(ca * op.mm(X) + cb * X - P)
     np.testing.assert_allclose(fused, generic, rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("op", ["spmm", "cheb_step", "spmv"])
+def test_ell_kernels_take_the_xla_path_on_tpu(monkeypatch, op):
+    """Mosaic refuses the kernels' in-kernel gather, so on a TPU ``auto``
+    runs the XLA path and ``impl="pallas"`` raises with the compiler's
+    reason (the backend is steered here; the dispatch reads it at trace)."""
+    import re
+
+    import jax
+
+    from repro.kernels.ell_spmm import ops as mm_ops
+    from repro.kernels.ell_spmv import ops as mv_ops
+
+    n = 64
+    W, coo = _random_sparse(n, 0.1, seed=3)
+    ell = csr_to_blockell(coo_to_csr(coo), block_rows=8, width_quantile=0.8)
+    X = jnp.asarray(np.random.default_rng(0).normal(size=(n, 2)), jnp.float32)
+    Xn = np.asarray(X)
+    fn, reason, want = {
+        "spmm": (lambda **kw: mm_ops.ell_spmm(ell, X, **kw),
+                 mm_ops.MOSAIC_REFUSAL, W @ Xn),
+        "cheb_step": (lambda **kw: mm_ops.ell_spmm_cheb_step(
+            ell, X, X, 1.0, 0.0, **kw), mm_ops.MOSAIC_REFUSAL, W @ Xn - Xn),
+        "spmv": (lambda **kw: mv_ops.ell_spmv(ell, X[:, 0], **kw),
+                 mv_ops.MOSAIC_REFUSAL, W @ Xn[:, 0]),
+    }[op]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    jax.clear_caches()
+    try:
+        with pytest.raises(NotImplementedError, match=re.escape(reason)):
+            fn(impl="pallas")
+        np.testing.assert_allclose(np.asarray(fn()), want, rtol=1e-4,
+                                   atol=1e-4)
+    finally:
+        jax.clear_caches()

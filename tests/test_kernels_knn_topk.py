@@ -149,3 +149,46 @@ def test_kernel_offset_traced_under_jit():
         np.testing.assert_allclose(np.asarray(got_d), np.asarray(ref_d),
                                    rtol=1e-4, atol=1e-4)
         np.testing.assert_array_equal(np.asarray(got_i), np.asarray(ref_i))
+
+
+def _lattice(n):
+    side = int(np.ceil(n ** (1 / 3)))
+    g = np.stack(np.meshgrid(*[np.arange(side)] * 3, indexing="ij"), -1)
+    return g.reshape(-1, 3)[:n].astype(np.float32)
+
+
+@pytest.mark.parametrize("n,k,bq,bk", [
+    (343, 16, 32, 64),    # 7³ lattice: ties straddle query and key tiles
+    (300, 16, 64, 128),   # partial lattice, n not a block multiple
+    (216, 7, 16, 32),     # k cuts through a shell of equal distances
+])
+def test_kernel_breaks_ties_by_id_on_a_lattice(n, k, bq, bk):
+    """Lattice distances are exact integers with many ties: the kernel must
+    return the k smallest in (dist², id) order — the one answer that the
+    sharded ring exchange's merge reproduces bitwise."""
+    x = _lattice(n)
+    dist, idx = knn_topk(jnp.asarray(x), k, impl="pallas", interpret=True,
+                         block_q=bq, block_k=bk)
+    want_d, want_i = _brute(x, k)
+    np.testing.assert_array_equal(np.asarray(idx), want_i)
+    np.testing.assert_array_equal(np.asarray(dist), want_d.astype(np.float32))
+
+
+def test_lattice_blocks_merged_in_ring_order_equal_full_pool():
+    """Four key blocks searched apart (query offsets as the ring passes
+    them) and merged in ring order give the full-pool (dist², id) answer."""
+    from repro.core.distributed_pipeline import merge_topk
+
+    n, k, nb = 512, 16, 128
+    x = jnp.asarray(_lattice(n))
+    kw = dict(impl="pallas", interpret=True, block_q=64, block_k=64)
+    d_full, i_full = knn_topk(x, k, **kw)
+    bd = jnp.full((n, k), jnp.inf, jnp.float32)
+    bi = jnp.full((n, k), -1, jnp.int32)
+    for src in (0, 3, 2, 1):
+        d_t, i_t = knn_topk(x[src * nb:(src + 1) * nb], k, queries=x,
+                            query_offset=-src * nb, **kw)
+        bd, bi = merge_topk(bd, bi, d_t,
+                            jnp.where(i_t >= 0, i_t + src * nb, -1), k)
+    np.testing.assert_array_equal(np.asarray(bi), np.asarray(i_full))
+    np.testing.assert_array_equal(np.asarray(bd), np.asarray(d_full))

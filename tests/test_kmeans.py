@@ -146,9 +146,9 @@ def test_kmeanspp_beats_random_init_inertia():
 
 
 def test_assign_auto_propagates_real_kernel_bugs(monkeypatch):
-    """`assign="auto"` may only fall back on unavailability (ImportError /
-    NotImplementedError) — a genuine kernel bug must propagate, not silently
-    degrade to the reference path (the pre-fix bare `except Exception`)."""
+    """`assign="auto"` never swaps the kernel for the reference behind the
+    caller's back: a kernel bug and a kernel that is unavailable (the error
+    a compiler refusal surfaces as) both propagate."""
     import repro.core.kmeans as km_mod
     import repro.kernels.kmeans_assign.ops as ops_mod
 
@@ -164,40 +164,18 @@ def test_assign_auto_propagates_real_kernel_bugs(monkeypatch):
         km_mod._assign(x, c, None, cfg)
 
     def unavailable(*a, **kw):
-        raise NotImplementedError("no TPU")
+        raise NotImplementedError("kernel refused")
 
     monkeypatch.setattr(ops_mod, "kmeans_assign", unavailable)
-    km_mod.reset_fallback_warnings()
-    with pytest.warns(RuntimeWarning, match="falling back"):
-        labels, dmin = km_mod._assign(x, c, None, cfg)
-    want_labels, want_dmin = assign_ref(x, c)
+    for assign in ("auto", "fused"):
+        with pytest.raises(NotImplementedError, match="kernel refused"):
+            km_mod._assign(x, c, None, KMeansConfig(k=3, iter="two_pass",
+                                                    assign=assign))
+    # assign="ref" is the explicit reference request and never calls it
+    labels, _ = km_mod._assign(x, c, None, KMeansConfig(
+        k=3, iter="two_pass", assign="ref"))
+    want_labels, _ = assign_ref(x, c)
     np.testing.assert_array_equal(np.asarray(labels), np.asarray(want_labels))
-    # warn-once: a second fallback is silent
-    import warnings as _warnings
-    with _warnings.catch_warnings():
-        _warnings.simplefilter("error")
-        km_mod._assign(x, c, None, cfg)
-    # assign="fused" re-raises even unavailability
-    with pytest.raises(NotImplementedError):
-        km_mod._assign(x, c, None, KMeansConfig(k=3, iter="two_pass", assign="fused"))
-
-
-def test_fallback_warn_state_is_resettable():
-    """The warn-once registry must not leak across tests: after the reset
-    hook, the next fallback warns again (the old module-global bool made
-    warn-order test-suite-dependent)."""
-    from repro.core.kmeans import reset_fallback_warnings, _warn_fallback_once
-
-    reset_fallback_warnings()
-    with pytest.warns(RuntimeWarning, match="first"):
-        _warn_fallback_once("k", "first")
-    import warnings as _warnings
-    with _warnings.catch_warnings():
-        _warnings.simplefilter("error")
-        _warn_fallback_once("k", "suppressed repeat")  # warn-once: silent
-    reset_fallback_warnings()
-    with pytest.warns(RuntimeWarning, match="first"):
-        _warn_fallback_once("k", "first again")
 
 
 @settings(max_examples=10, deadline=None)

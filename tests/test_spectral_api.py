@@ -8,8 +8,7 @@ Covers the redesign's contracts:
 * nested configs validate their string enums at construction and round-trip
   through JSON (serve/dry-run reproducibility);
 * the drop_first path is exercised end-to-end (embedding width + eigenvalue
-  bookkeeping);
-* the Stage-1 GSPMD re-replication workaround is version-gated.
+  bookkeeping).
 """
 import json
 import warnings
@@ -312,24 +311,6 @@ def test_standalone_kmeans_requires_k():
 
     with pytest.raises(ValueError, match="k is unset"):
         kmeans(jnp.zeros((8, 2)), KMeansConfig(), jax.random.PRNGKey(0))
-
-
-# ---------------------------------------------------------------------------
-# GSPMD re-replication workaround version gate
-# ---------------------------------------------------------------------------
-
-def test_argsort_gather_workaround_gate():
-    from repro.compat import needs_argsort_gather_workaround
-
-    assert needs_argsort_gather_workaround("0.4.37")
-    assert needs_argsort_gather_workaround("0.4.37.dev20240101")
-    assert not needs_argsort_gather_workaround("0.5.0")
-    assert not needs_argsort_gather_workaround("0.7.2")
-    assert not needs_argsort_gather_workaround("1.0")
-    # the live gate matches the pinned jax
-    expected = tuple(int("".join(c for c in p if c.isdigit()))
-                     for p in jax.__version__.split(".")[:2]) < (0, 5)
-    assert needs_argsort_gather_workaround() == expected
 
 
 # ---------------------------------------------------------------------------

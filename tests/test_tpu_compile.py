@@ -1,0 +1,131 @@
+"""Compile the Pallas kernels of the main path for a described TPU v5e.
+
+Nothing runs: the TPU compiler, which is installed without a chip, compiles
+each kernel at the DTI widths for one chip of a ``v5e:2x2`` topology and
+raises what the chip's compiler would raise (tile alignment, scoped-VMEM
+limits) — what interpret-mode tests cannot see.  ``ell_spmm``/``ell_spmv``
+are not here: Mosaic refuses their in-kernel gather, so a TPU runs their XLA
+path (tests/test_kernels_ell_spmm.py checks that refusal).
+
+The topology is described inside a fixture, never at import: only one
+process may load the TPU library at a time, and the test workers all import
+this file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here: nothing to check
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def _no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache but
+    cannot be read back without one; keep these compiles out of it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    cc.reset_cache()
+
+
+def _compile(fn, one_chip, *shapes):
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text  # the Pallas kernel is in the program
+    return text
+
+
+F32, I32 = jnp.float32, jnp.int32
+
+
+@pytest.mark.parametrize("d,k", [(3, 16), (90, 10), (200, 64)],
+                         ids=["dti_stage1", "serving_oos", "merge_vmem_cap"])
+def test_knn_topk_compiles(one_chip, d, k):
+    from repro.kernels.knn_topk.ops import knn_topk
+
+    _compile(lambda x: knn_topk(x, k, impl="pallas", interpret=False),
+             one_chip, ((4096, d), F32))
+
+
+@pytest.mark.parametrize("k,d", [(500, 500), (1024, 639)],
+                         ids=["dti_stage3", "vmem_budget_edge"])
+def test_kmeans_iter_compiles(one_chip, k, d):
+    from repro.kernels.kmeans_iter.ops import (ACC_VMEM_BUDGET_BYTES,
+                                               kmeans_iter,
+                                               pallas_workset_bytes)
+
+    assert pallas_workset_bytes(4096, d, k) <= ACC_VMEM_BUDGET_BYTES
+    _compile(lambda x, c: kmeans_iter(x, c, impl="pallas", interpret=False),
+             one_chip, ((4096, d), F32), ((k, d), F32))
+
+
+def test_kmeans_assign_compiles(one_chip):
+    from repro.kernels.kmeans_assign.ops import kmeans_assign
+
+    _compile(lambda x, c: kmeans_assign(x, c, impl="pallas", interpret=False),
+             one_chip, ((4096, 500), F32), ((500, 500), F32))
+
+
+def test_lsh_hash_codes_compile(one_chip):
+    from repro.kernels.lsh_candidates.ops import hash_codes
+
+    _compile(lambda x, p: hash_codes(x, p, impl="pallas", interpret=False),
+             one_chip, ((4096, 90), F32), ((16, 90, 17), F32))
+
+
+@pytest.mark.parametrize("exchange", [None, "gather", "ring"],
+                         ids=["one_chip", "four_chips_gather",
+                              "four_chips_ring"])
+def test_dti_pipeline_compiles(topo, one_chip, monkeypatch, exchange):
+    """The whole DTI program (Stage 1 kNN → Lanczos → fused k-means) at a
+    small n, on one chip and sharded over four.  ``jax.default_backend`` is
+    steered to "tpu" so ``impl="auto"`` takes its TPU branches, as on the
+    chip; across four chips every Mosaic kernel must sit in a shard_map."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.core.spectral import (EigConfig, GraphConfig, Plan,
+                                     SpectralPipeline)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    jax.clear_caches()
+    plan, where = Plan(), one_chip
+    if exchange is not None:
+        mesh = Mesh(np.array(topo.devices), ("data",))
+        plan = Plan(device="sharded", mesh=mesh, stage1_exchange=exchange)
+        where = NamedSharding(mesh, P())
+    pipe = SpectralPipeline(
+        n_clusters=12, graph=GraphConfig(knn_k=16, measure="cross_correlation"),
+        eig=EigConfig(tol=1e-4), plan=plan)
+    n = 1001  # odd: the sharded Stage 1 pads its row blocks
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=where)
+            for s, dt in (((n, 90), F32), ((n, 3), F32), ((2,), jnp.uint32))]
+    try:
+        text = jax.jit(lambda x, p, key: pipe.run(x, key, points=p)).lower(
+            *args).compile().as_text()
+    finally:
+        jax.clear_caches()
+    assert text.count("tpu_custom_call") >= 2  # knn_topk and kmeans_iter
