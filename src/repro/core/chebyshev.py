@@ -54,7 +54,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.lanczos import LanczosResult
+from repro.core.lanczos import LanczosResult, scoped
 
 Array = jax.Array
 
@@ -171,6 +171,12 @@ def filter_response(lam: Array, a: Array, lo: Array, hi: Array,
 # Interval selection
 # ---------------------------------------------------------------------------
 
+def bounds_steps(n: int, iters: int) -> int:
+    """Lanczos steps (one ``op.mv`` each) :func:`estimate_spectral_bounds`
+    runs on an ``n``-dimensional operator."""
+    return min(iters, max(2, n - 1))
+
+
 def estimate_spectral_bounds(op, key: Array, *, iters: int = 12,
                              margin: float = 0.01) -> Tuple[Array, Array]:
     """[lo, hi] ⊇ spec(op) from ``iters`` plain Lanczos steps on ``op.mv``.
@@ -182,7 +188,7 @@ def estimate_spectral_bounds(op, key: Array, *, iters: int = 12,
     the spectrum would make the recurrence diverge geometrically.
     """
     n = op.shape[0]
-    steps = min(iters, max(2, n - 1))
+    steps = bounds_steps(n, iters)
     f32 = jnp.float32
     v = jax.random.normal(key, (n,), f32)
     v = v / jnp.maximum(jnp.linalg.norm(v), 1e-30)
@@ -351,6 +357,7 @@ def chebyshev_eigsh(op, cfg: ChebConfig, *, v0: Optional[Array] = None,
     key = jax.random.PRNGKey(0) if key is None else key
     f32 = jnp.float32
     sign = 1.0 if cfg.which == "LA" else -1.0  # "SA" filters -A's top
+    op = _Scoped(op)
 
     k_bounds, k_mom, k_sketch = jax.random.split(key, 3)
     lo, hi = estimate_spectral_bounds(
@@ -373,23 +380,30 @@ def chebyshev_eigsh(op, cfg: ChebConfig, *, v0: Optional[Array] = None,
         g = g.at[:, 0].set(v)
 
     y = chebyshev_filter(op, g, lo, hi, a, cfg.degree, sign=sign)
-    q, _ = jnp.linalg.qr(y)  # [n, R] whitened basis
+    with jax.named_scope("orthogonalize"):
+        q, _ = jnp.linalg.qr(y)  # [n, R] whitened basis
     aq = sign * op.mm(q).astype(f32)  # ONE extra stream
-    b = q.T @ aq
-    b = 0.5 * (b + b.T)
-    theta, s = jnp.linalg.eigh(b)  # ascending [R]
+    with jax.named_scope("restart"):
+        b = q.T @ aq
+        b = 0.5 * (b + b.T)
+        theta, s = jnp.linalg.eigh(b)  # ascending [R]
 
-    kk = min(cfg.k, r)
-    sel = s[:, r - kk:][:, ::-1]  # top-kk, descending
-    vals = theta[r - kk:][::-1]
-    u = q @ sel  # [n, kk] Ritz vectors
-    resid = jnp.linalg.norm(aq @ sel - u * vals[None, :], axis=0)
+        kk = min(cfg.k, r)
+        sel = s[:, r - kk:][:, ::-1]  # top-kk, descending
+        vals = theta[r - kk:][::-1]
+        u = q @ sel  # [n, kk] Ritz vectors
+        resid = jnp.linalg.norm(aq @ sel - u * vals[None, :], axis=0)
+    # every loop above has a static trip count, so the applications it
+    # executed are the sum of those counts
+    apps = (bounds_steps(n, cfg.bounds_iters) + cfg.degree + 1
+            + (cfg.degree if cfg.lambda_cut is None else 0))
     return LanczosResult(
         eigenvalues=(vals * sign).astype(cfg.dtype),
         eigenvectors=u.astype(cfg.dtype),
         residuals=resid.astype(cfg.dtype),
         restarts=jnp.asarray(0),
         converged=jnp.asarray(True),
+        operator_applications=jnp.asarray(apps, jnp.int32),
     )
 
 
@@ -409,6 +423,20 @@ def diverged(laplacian_eigenvalues, *, slack: float = 0.5) -> bool:
     if not np.isfinite(vals).all():
         return True
     return bool(np.max(np.abs(1.0 - vals)) > 1.0 + slack)
+
+
+class _Scoped:
+    """Operator view whose every application (``mv``, ``mm`` and the fused
+    ``cheb_step`` where the operator has one) traces under the ``spmv``
+    scope."""
+
+    def __init__(self, op):
+        self.shape = op.shape
+        self.mv = scoped("spmv", op.mv)
+        self.mm = scoped("spmv", op.mm)
+        fused = getattr(op, "cheb_step", None)
+        if fused is not None:
+            self.cheb_step = scoped("spmv", fused)
 
 
 class _signed:

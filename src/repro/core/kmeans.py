@@ -257,10 +257,12 @@ def random_init(x: Array, k: int, key: Array) -> Array:
 
 
 def seed_centroids(x: Array, cfg: KMeansConfig, key: Array) -> Array:
-    """Dispatch the configured seeding (shared with the sharded driver)."""
-    if cfg.init == "kmeans++":
-        return kmeanspp_init(x, cfg.k, key)
-    return random_init(x, cfg.k, key)
+    """Dispatch the configured seeding (shared with the sharded Lloyd loop),
+    traced under the ``kmeans_seed`` scope."""
+    with jax.named_scope("kmeans_seed"):
+        if cfg.init == "kmeans++":
+            return kmeanspp_init(x, cfg.k, key)
+        return random_init(x, cfg.k, key)
 
 
 # ---------------------------------------------------------------------------

@@ -54,6 +54,7 @@ class LanczosResult(NamedTuple):
     residuals: Array  # [k]  |beta_m * s_{m,i}| per returned pair
     restarts: Array  # []   restart count actually executed
     converged: Array  # []   bool
+    operator_applications: Array = None  # [] int32 mv/mm calls executed
 
 
 @dataclasses.dataclass(frozen=True)
@@ -212,6 +213,17 @@ def escalate_basis(cfg: LanczosConfig, n: int, *,
         cfg, m=max(m, cfg.m), max_restarts=max(1, cfg.max_restarts) * 2)
 
 
+def scoped(name: str, fn: Callable) -> Callable:
+    """``fn`` traced under ``jax.named_scope(name)``: the Stage-2 solvers
+    wrap each operator application in ``scoped("spmv", ...)`` so that
+    every operator class, whatever its kernel, shows under one scope in the
+    compiled program's metadata and the device trace (DESIGN.md §18)."""
+    def call(*args):
+        with jax.named_scope(name):
+            return fn(*args)
+    return call
+
+
 def _orthonormal_against(v: Array, basis: Array, key: Array) -> Array:
     """Random unit vector orthogonal to the (zero-padded) basis rows —
     invariant-subspace escape hatch (ARPACK does the same on breakdown)."""
@@ -289,6 +301,7 @@ def _lanczos_topk_single(
 ) -> LanczosResult:
     """Single-vector thick-restart Lanczos (the ``block_size=1`` engine)."""
     assert matvec is not None, "need matvec for block_size=1"
+    matvec = scoped("spmv", matvec)
     k, m = cfg.k, cfg.m
     assert 0 < k < m <= n, (k, m, n)
     key = jax.random.PRNGKey(0) if key is None else key
@@ -303,48 +316,51 @@ def _lanczos_topk_single(
 
     def step(j, carry):
         """One Lanczos step: expand basis row j+1, record T row/col j."""
-        V, T, key = carry
+        V, T, key, apps = carry
         w = matvec(V[j]).astype(f32) * sign
-        c = V @ w  # [m+1] couplings (zero rows -> zero coeffs)
-        T = T.at[j, :].set(c)
-        T = T.at[:, j].set(c)
-        w = w - V.T @ c
-        c2 = V @ w  # second Gram-Schmidt pass
-        w = w - V.T @ c2
-        beta = jnp.linalg.norm(w)
-        key, sub = jax.random.split(key)
-        v_next = jnp.where(
-            beta > 1e-10, w / jnp.maximum(beta, 1e-30), _orthonormal_against(w, V, sub)
-        )
-        V = V.at[j + 1].set(v_next)
-        T = T.at[j + 1, j].set(beta)
-        T = T.at[j, j + 1].set(beta)
-        return V, T, key
+        with jax.named_scope("orthogonalize"):
+            c = V @ w  # [m+1] couplings (zero rows -> zero coeffs)
+            T = T.at[j, :].set(c)
+            T = T.at[:, j].set(c)
+            w = w - V.T @ c
+            c2 = V @ w  # second Gram-Schmidt pass
+            w = w - V.T @ c2
+            beta = jnp.linalg.norm(w)
+            key, sub = jax.random.split(key)
+            v_next = jnp.where(
+                beta > 1e-10, w / jnp.maximum(beta, 1e-30),
+                _orthonormal_against(w, V, sub))
+            V = V.at[j + 1].set(v_next)
+            T = T.at[j + 1, j].set(beta)
+            T = T.at[j, j + 1].set(beta)
+        return V, T, key, apps + 1
 
-    def run_cycle(V, T, l, key):
+    def run_cycle(V, T, l, key, apps):
         """Steps l..m-1, then Ritz extraction + thick restart state."""
-        V, T, key = jax.lax.fori_loop(l, m, step, (V, T, key))
-        beta_m = T[m, m - 1]
-        theta, S = jnp.linalg.eigh(T[:m, :m])  # ascending
-        # top-k live in the last k columns
-        res = jnp.abs(beta_m * S[m - 1, :])
-        scale = jnp.maximum(jnp.max(jnp.abs(theta)), 1e-12)
-        conv = res[m - k :] <= cfg.tol * scale
-        n_conv = conv.sum()
+        V, T, key, apps = jax.lax.fori_loop(l, m, step, (V, T, key, apps))
+        with jax.named_scope("restart"):
+            beta_m = T[m, m - 1]
+            theta, S = jnp.linalg.eigh(T[:m, :m])  # ascending
+            # top-k live in the last k columns
+            res = jnp.abs(beta_m * S[m - 1, :])
+            scale = jnp.maximum(jnp.max(jnp.abs(theta)), 1e-12)
+            conv = res[m - k :] <= cfg.tol * scale
+            n_conv = conv.sum()
 
-        # ---- thick restart: keep l_keep top Ritz pairs + residual vector
-        l_keep = restart_keep_size(cfg)
-        keep = slice(m - l_keep, m)
-        Y = (S[:, keep].T @ V[:m]).astype(f32)  # [l_keep, n] Ritz vectors
-        V_new = jnp.zeros_like(V)
-        V_new = V_new.at[:l_keep].set(Y)
-        V_new = V_new.at[l_keep].set(V[m])
-        h = beta_m * S[m - 1, keep]
-        T_new = jnp.zeros_like(T)
-        T_new = T_new.at[jnp.arange(l_keep), jnp.arange(l_keep)].set(theta[keep])
-        T_new = T_new.at[l_keep, :l_keep].set(h)
-        T_new = T_new.at[:l_keep, l_keep].set(h)
-        return (V_new, T_new, key, theta, S, V, res), n_conv, l_keep
+            # ---- thick restart: keep l_keep top Ritz pairs + residual vector
+            l_keep = restart_keep_size(cfg)
+            keep = slice(m - l_keep, m)
+            Y = (S[:, keep].T @ V[:m]).astype(f32)  # [l_keep, n] Ritz vectors
+            V_new = jnp.zeros_like(V)
+            V_new = V_new.at[:l_keep].set(Y)
+            V_new = V_new.at[l_keep].set(V[m])
+            h = beta_m * S[m - 1, keep]
+            T_new = jnp.zeros_like(T)
+            T_new = T_new.at[jnp.arange(l_keep), jnp.arange(l_keep)].set(
+                theta[keep])
+            T_new = T_new.at[l_keep, :l_keep].set(h)
+            T_new = T_new.at[:l_keep, l_keep].set(h)
+        return (V_new, T_new, key, theta, S, V, res, apps), n_conv, l_keep
 
     V0 = jnp.zeros((m + 1, n), f32).at[0].set(v0)
     T0 = jnp.zeros((m + 1, m + 1), f32)
@@ -357,10 +373,10 @@ def _lanczos_topk_single(
     # loop the steady-state cycle (while_loop in production; fori_loop with a
     # static trip count for the dry-run so cost_analysis sees exact op counts).
     def first_cycle(V, T, key):
-        return run_cycle(V, T, 0, key)
+        return run_cycle(V, T, 0, key, jnp.asarray(0, jnp.int32))
 
-    def steady_cycle(V, T, key):
-        return run_cycle(V, T, l_keep_static, key)
+    def steady_cycle(V, T, key, apps):
+        return run_cycle(V, T, l_keep_static, key, apps)
 
     out, n_conv, _ = first_cycle(V0, T0, key)
 
@@ -368,11 +384,11 @@ def _lanczos_topk_single(
         # static restart count — used by the dry-run so cost_analysis sees an
         # exact, analyzable op count (no while loop).
         def fbody(_, st):
-            (V, T, key, *_), _ = st
-            o, nc, _ = steady_cycle(V, T, key)
+            (V, T, key, *_, apps), _ = st
+            o, nc, _ = steady_cycle(V, T, key, apps)
             return o, nc
 
-        (V, T, key, theta, S, V_old, res), n_conv = jax.lax.fori_loop(
+        (V, T, key, theta, S, V_old, res, apps), n_conv = jax.lax.fori_loop(
             0, cfg.fixed_restarts, fbody, (out, n_conv)
         )
         restarts = jnp.asarray(1 + cfg.fixed_restarts)
@@ -382,26 +398,27 @@ def _lanczos_topk_single(
             return jnp.logical_and(it < cfg.max_restarts, nc < k)
 
         def wbody(st):
-            (V, T, key, *_), it, _ = st
-            o, nc, _ = steady_cycle(V, T, key)
+            (V, T, key, *_, apps), it, _ = st
+            o, nc, _ = steady_cycle(V, T, key, apps)
             return o, it + 1, nc
 
-        (V, T, key, theta, S, V_old, res), restarts, n_conv = jax.lax.while_loop(
-            wcond, wbody, (out, jnp.asarray(1), n_conv)
-        )
+        (V, T, key, theta, S, V_old, res, apps), restarts, n_conv = \
+            jax.lax.while_loop(wcond, wbody, (out, jnp.asarray(1), n_conv))
 
     # --- extract final top-k pairs from the last completed cycle ----------
-    topk = slice(m - k, m)
-    vals = theta[topk][::-1] * sign  # descending, undo "SA" negation
-    U = (S[:, topk].T @ V_old[:m]).astype(cfg.dtype)  # [k, n]
-    U = U[::-1].T  # [n, k] descending order
-    res_k = res[topk][::-1]
+    with jax.named_scope("restart"):
+        topk = slice(m - k, m)
+        vals = theta[topk][::-1] * sign  # descending, undo "SA" negation
+        U = (S[:, topk].T @ V_old[:m]).astype(cfg.dtype)  # [k, n]
+        U = U[::-1].T  # [n, k] descending order
+        res_k = res[topk][::-1]
     return LanczosResult(
         eigenvalues=vals.astype(cfg.dtype),
         eigenvectors=U,
         residuals=res_k.astype(cfg.dtype),
         restarts=restarts,
         converged=n_conv >= k,
+        operator_applications=apps,
     )
 
 
@@ -449,6 +466,7 @@ def _lanczos_topk_block(
         f"shrink block_size or the basis m for this problem size"
     )
     assert m >= k + 2 * b, f"block mode needs m >= k + 2b (m={m}, k={k}, b={b})"
+    matmat = scoped("spmv", matmat)
     key = jax.random.PRNGKey(0) if key is None else key
     f32 = jnp.float32
 
@@ -465,70 +483,74 @@ def _lanczos_topk_block(
     def make_step(l):
         def step(i, carry):
             """One block step: expand basis rows j+b..j+2b-1, record T blocks."""
-            V, T, key = carry
+            V, T, key, apps = carry
             j = l + i * b
             Vj = jax.lax.dynamic_slice_in_dim(V, j, b, axis=0)  # [b, n]
             W = matmat(Vj.T).astype(f32).T * sign  # [b, n] — ONE operator stream
-            C = V @ W.T  # [m+b, b] couplings (zero rows -> zero coeffs)
-            T = jax.lax.dynamic_update_slice(T, C, (0, j))
-            T = jax.lax.dynamic_update_slice(T, C.T, (j, 0))
-            W = W - C.T @ V
-            C2 = V @ W.T  # second Gram-Schmidt pass
-            W = W - C2.T @ V
-            # in-block orthonormalization: W.T = Q R, band block B = R2 @ R
-            Q, R = jnp.linalg.qr(W.T)  # [n, b], [b, b]
-            key, sub = jax.random.split(key)
-            ok = jnp.abs(jnp.diagonal(R)) > 1e-10
-            E = _orthonormal_block_against(W.T, V, sub)
-            Qf = jnp.where(ok[None, :], Q, E)  # escape deficient directions
-            Qf = Qf - V.T @ (V @ Qf)  # cleanup vs old basis (no-op if full rank)
-            Q2, R2 = jnp.linalg.qr(Qf)
-            B = R2 @ R  # deficient columns of R are ~0 -> ~zero coupling
-            V = jax.lax.dynamic_update_slice(V, Q2.T, (j + b, 0))
-            T = jax.lax.dynamic_update_slice(T, B, (j + b, j))
-            T = jax.lax.dynamic_update_slice(T, B.T, (j, j + b))
-            return V, T, key
+            with jax.named_scope("orthogonalize"):
+                C = V @ W.T  # [m+b, b] couplings (zero rows -> zero coeffs)
+                T = jax.lax.dynamic_update_slice(T, C, (0, j))
+                T = jax.lax.dynamic_update_slice(T, C.T, (j, 0))
+                W = W - C.T @ V
+                C2 = V @ W.T  # second Gram-Schmidt pass
+                W = W - C2.T @ V
+                # in-block orthonormalization: W.T = Q R, band block B = R2 @ R
+                Q, R = jnp.linalg.qr(W.T)  # [n, b], [b, b]
+                key, sub = jax.random.split(key)
+                ok = jnp.abs(jnp.diagonal(R)) > 1e-10
+                E = _orthonormal_block_against(W.T, V, sub)
+                Qf = jnp.where(ok[None, :], Q, E)  # escape deficient directions
+                Qf = Qf - V.T @ (V @ Qf)  # cleanup vs old basis (no-op if full rank)
+                Q2, R2 = jnp.linalg.qr(Qf)
+                B = R2 @ R  # deficient columns of R are ~0 -> ~zero coupling
+                V = jax.lax.dynamic_update_slice(V, Q2.T, (j + b, 0))
+                T = jax.lax.dynamic_update_slice(T, B, (j + b, j))
+                T = jax.lax.dynamic_update_slice(T, B.T, (j, j + b))
+            return V, T, key, apps + 1
 
         return step
 
-    def run_cycle(V, T, l, key):
+    def run_cycle(V, T, l, key, apps):
         """Block steps l..m-b (stride b), then Ritz extraction + restart state."""
-        V, T, key = jax.lax.fori_loop(0, (m - l) // b, make_step(l), (V, T, key))
-        Bm = T[m : m + b, m - b : m]  # last band coupling block
-        theta, S = jnp.linalg.eigh(T[:m, :m])  # ascending
-        # residual of Ritz pair i: ‖B_m · S[m-b:m, i]‖  (top-k in last k cols)
-        res = jnp.linalg.norm(Bm @ S[m - b :, :], axis=0)
-        scale = jnp.maximum(jnp.max(jnp.abs(theta)), 1e-12)
-        conv = res[m - k :] <= cfg.tol * scale
-        n_conv = conv.sum()
+        V, T, key, apps = jax.lax.fori_loop(0, (m - l) // b, make_step(l),
+                                            (V, T, key, apps))
+        with jax.named_scope("restart"):
+            Bm = T[m : m + b, m - b : m]  # last band coupling block
+            theta, S = jnp.linalg.eigh(T[:m, :m])  # ascending
+            # residual of Ritz pair i: ‖B_m · S[m-b:m, i]‖  (top-k in last k cols)
+            res = jnp.linalg.norm(Bm @ S[m - b :, :], axis=0)
+            scale = jnp.maximum(jnp.max(jnp.abs(theta)), 1e-12)
+            conv = res[m - k :] <= cfg.tol * scale
+            n_conv = conv.sum()
 
-        # ---- thick restart: l_keep top Ritz pairs + the b residual columns
-        keep = slice(m - l_keep, m)
-        Y = (S[:, keep].T @ V[:m]).astype(f32)  # [l_keep, n] Ritz vectors
-        V_new = jnp.zeros_like(V)
-        V_new = V_new.at[:l_keep].set(Y)
-        V_new = V_new.at[l_keep : l_keep + b].set(V[m : m + b])
-        H = Bm @ S[m - b :, keep]  # [b, l_keep] restart couplings
-        T_new = jnp.zeros_like(T)
-        T_new = T_new.at[jnp.arange(l_keep), jnp.arange(l_keep)].set(theta[keep])
-        T_new = T_new.at[l_keep : l_keep + b, :l_keep].set(H)
-        T_new = T_new.at[:l_keep, l_keep : l_keep + b].set(H.T)
-        return (V_new, T_new, key, theta, S, V, res), n_conv
+            # ---- thick restart: l_keep top Ritz pairs + the b residual columns
+            keep = slice(m - l_keep, m)
+            Y = (S[:, keep].T @ V[:m]).astype(f32)  # [l_keep, n] Ritz vectors
+            V_new = jnp.zeros_like(V)
+            V_new = V_new.at[:l_keep].set(Y)
+            V_new = V_new.at[l_keep : l_keep + b].set(V[m : m + b])
+            H = Bm @ S[m - b :, keep]  # [b, l_keep] restart couplings
+            T_new = jnp.zeros_like(T)
+            T_new = T_new.at[jnp.arange(l_keep), jnp.arange(l_keep)].set(
+                theta[keep])
+            T_new = T_new.at[l_keep : l_keep + b, :l_keep].set(H)
+            T_new = T_new.at[:l_keep, l_keep : l_keep + b].set(H.T)
+        return (V_new, T_new, key, theta, S, V, res, apps), n_conv
 
     V0 = jnp.zeros((m + b, n), f32).at[:b].set(Q0.T)
     T0 = jnp.zeros((m + b, m + b), f32)
 
-    out, n_conv = run_cycle(V0, T0, 0, key)
+    out, n_conv = run_cycle(V0, T0, 0, key, jnp.asarray(0, jnp.int32))
 
-    def steady_cycle(V, T, key):
-        return run_cycle(V, T, l_keep, key)
+    def steady_cycle(V, T, key, apps):
+        return run_cycle(V, T, l_keep, key, apps)
 
     if cfg.fixed_restarts is not None:
         def fbody(_, st):
-            (V, T, key, *_), _ = st
-            return steady_cycle(V, T, key)
+            (V, T, key, *_, apps), _ = st
+            return steady_cycle(V, T, key, apps)
 
-        (V, T, key, theta, S, V_old, res), n_conv = jax.lax.fori_loop(
+        (V, T, key, theta, S, V_old, res, apps), n_conv = jax.lax.fori_loop(
             0, cfg.fixed_restarts, fbody, (out, n_conv)
         )
         restarts = jnp.asarray(1 + cfg.fixed_restarts)
@@ -538,24 +560,25 @@ def _lanczos_topk_block(
             return jnp.logical_and(it < cfg.max_restarts, nc < k)
 
         def wbody(st):
-            (V, T, key, *_), it, _ = st
-            o, nc = steady_cycle(V, T, key)
+            (V, T, key, *_, apps), it, _ = st
+            o, nc = steady_cycle(V, T, key, apps)
             return o, it + 1, nc
 
-        (V, T, key, theta, S, V_old, res), restarts, n_conv = jax.lax.while_loop(
-            wcond, wbody, (out, jnp.asarray(1), n_conv)
-        )
+        (V, T, key, theta, S, V_old, res, apps), restarts, n_conv = \
+            jax.lax.while_loop(wcond, wbody, (out, jnp.asarray(1), n_conv))
 
     # --- extract final top-k pairs from the last completed cycle ----------
-    topk = slice(m - k, m)
-    vals = theta[topk][::-1] * sign  # descending, undo "SA" negation
-    U = (S[:, topk].T @ V_old[:m]).astype(cfg.dtype)  # [k, n]
-    U = U[::-1].T  # [n, k] descending order
-    res_k = res[topk][::-1]
+    with jax.named_scope("restart"):
+        topk = slice(m - k, m)
+        vals = theta[topk][::-1] * sign  # descending, undo "SA" negation
+        U = (S[:, topk].T @ V_old[:m]).astype(cfg.dtype)  # [k, n]
+        U = U[::-1].T  # [n, k] descending order
+        res_k = res[topk][::-1]
     return LanczosResult(
         eigenvalues=vals.astype(cfg.dtype),
         eigenvectors=U,
         residuals=res_k.astype(cfg.dtype),
         restarts=restarts,
         converged=n_conv >= k,
+        operator_applications=apps,
     )

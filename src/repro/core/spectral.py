@@ -96,6 +96,7 @@ class SpectralResult(NamedTuple):
     lanczos_restarts: Array
     kmeans_iterations: Array
     reports: Tuple[StageReport, ...] = ()  # per-stage health trail (run())
+    operator_applications: Any = None  # [] Stage-2 mv/mm calls (all attempts)
 
 
 def default_basis_size(n: int, k: int, b: int = 1) -> int:
@@ -356,6 +357,7 @@ class EmbedState(NamedTuple):
     residuals: Array  # eigensolver residuals (pre drop_first bookkeeping)
     restarts: Array  # [] Lanczos restart count
     converged: Any = True  # [] solver convergence flag (bool or 0-d array)
+    operator_applications: Any = None  # [] operator mv/mm calls executed
 
 
 # ---------------------------------------------------------------------------
@@ -604,6 +606,10 @@ class SpectralPipeline:
     def prepare(self, w: Union[COO, ShardedCOO]) -> GraphState:
         """Admit a prebuilt similarity graph as Stage-1 output (normalize +
         degree bookkeeping).  Accepts a COO or a row-partitioned ShardedCOO."""
+        with jax.named_scope("stage1"):
+            return self._normalize(w)
+
+    def _normalize(self, w: Union[COO, ShardedCOO]) -> GraphState:
         if isinstance(w, ShardedCOO):
             ones = jnp.ones((w.shape[0],), jnp.float32)
             deg = spmv_gspmd(w, ones)  # degree pass (cheap, once)
@@ -627,6 +633,10 @@ class SpectralPipeline:
         on both plans — the sharded path searches the row-block-sharded
         ``points`` and weighs edges from the gathered ``x`` features.
         """
+        with jax.named_scope("stage1"):
+            return self._build_graph(x, points)
+
+    def _build_graph(self, x: Array, points: Optional[Array]) -> GraphState:
         g = self.graph
         if self.plan.device == "sharded":
             # the single-device branch delegates this check to build_knn_graph
@@ -652,13 +662,13 @@ class SpectralPipeline:
             dist2, idx = knn(p)
             w = graph_from_knn(x, dist2, idx, measure=g.measure, sigma=g.sigma,
                                eps=g.eps, dist2_in_x_space=points is None)
-            return self.prepare(w)
+            return self._normalize(w)
         w = build_knn_graph(
             x, g.knn_k, points=points, measure=g.measure, sigma=g.sigma,
             eps=g.eps, method=g.method, n_tables=g.n_tables, n_bits=g.n_bits,
             candidates=g.candidates, lsh_seed=g.lsh_seed, impl=g.impl,
             block_q=g.block_q, block_k=g.block_k, interpret=g.interpret)
-        return self.prepare(w)
+        return self._normalize(w)
 
     # -- Stage 2 ------------------------------------------------------------
 
@@ -675,25 +685,29 @@ class SpectralPipeline:
         n = state.adj.shape[0]
         op = self.operator(state) if operator is None else operator
         scfg = self._eig_config(n, eig)
-        # deterministic, informative start: D^{1/2}·1 is exactly the trivial
-        # eigenvector of A_sym — Lanczos deflates it in one step (the
-        # chebyshev path seeds its sketch with it for the same reason).
-        v0 = jnp.sqrt(jnp.maximum(state.deg.astype(jnp.float32), 0.0)) + 1e-3
-        ecfg = eig if eig is not None else self.eig
-        res = lz.eigsh(op, scfg, v0=v0, key=key)
-        vecs = res.eigenvectors
-        vals = res.eigenvalues
-        if ecfg.drop_first:
-            vecs = vecs[:, 1:]
-            vals = vals[1:]
-        h = lap.embed_rows(vecs, state.inv_sqrt_deg)
-        return EmbedState(
-            embedding=h,
-            eigenvalues=lap.smallest_laplacian_eigs_from_adj(vals),
-            residuals=res.residuals,
-            restarts=res.restarts,
-            converged=res.converged,
-        )
+        with jax.named_scope("stage2"):
+            # deterministic, informative start: D^{1/2}·1 is exactly the
+            # trivial eigenvector of A_sym — Lanczos deflates it in one step
+            # (the chebyshev path seeds its sketch with it for the same
+            # reason).
+            v0 = jnp.sqrt(jnp.maximum(state.deg.astype(jnp.float32), 0.0)) \
+                + 1e-3
+            ecfg = eig if eig is not None else self.eig
+            res = lz.eigsh(op, scfg, v0=v0, key=key)
+            vecs = res.eigenvectors
+            vals = res.eigenvalues
+            if ecfg.drop_first:
+                vecs = vecs[:, 1:]
+                vals = vals[1:]
+            h = lap.embed_rows(vecs, state.inv_sqrt_deg)
+            return EmbedState(
+                embedding=h,
+                eigenvalues=lap.smallest_laplacian_eigs_from_adj(vals),
+                residuals=res.residuals,
+                restarts=res.restarts,
+                converged=res.converged,
+                operator_applications=res.operator_applications,
+            )
 
     # -- Stage 3 ------------------------------------------------------------
 
@@ -709,7 +723,8 @@ class SpectralPipeline:
         """
         base = kmeans if kmeans is not None else self.kmeans
         kcfg = base.resolved(n_clusters or self.n_clusters)
-        res = self._run_kmeans(state.embedding, kcfg, key)
+        with jax.named_scope("stage3"):
+            res = self._run_kmeans(state.embedding, kcfg, key)
         return SpectralResult(
             labels=res.labels,
             embedding=state.embedding,
@@ -718,6 +733,7 @@ class SpectralPipeline:
             kmeans_inertia=res.inertia,
             lanczos_restarts=state.restarts,
             kmeans_iterations=res.iterations,
+            operator_applications=state.operator_applications,
         )
 
     def _kmeans_sharded_dispatch(self, n: int, d: int,
@@ -901,6 +917,7 @@ class SpectralPipeline:
         # pre-guard key — the no-fault path stays bitwise-identical
         ecfg = self.eig
         emb = self.embed(st.graph, st.key_embed, operator=op, eig=ecfg)
+        applied = emb.operator_applications
         attempts = 1
         rungs = list(notes)
         failure = None
@@ -919,8 +936,10 @@ class SpectralPipeline:
                 rungs.append(rung)
                 key = jax.random.fold_in(st.key_embed, attempts)
                 emb = self.embed(st.graph, key, operator=op, eig=ecfg)
+                applied = applied + emb.operator_applications
                 attempts += 1
                 failure = self._embed_failure(emb, ecfg)
+            emb = emb._replace(operator_applications=applied)
             if failure in ("nonfinite", "cheb_diverged"):
                 raise PipelineError(
                     "embed",
@@ -973,6 +992,7 @@ class SpectralPipeline:
             residuals=resid,
             restarts=st.embedding.restarts,
             converged=st.embedding.converged,
+            operator_applications=st.embedding.operator_applications,
         )
         return dataclasses.replace(
             st, graph=fine, embedding=emb, reduction=None,

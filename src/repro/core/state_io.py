@@ -126,11 +126,17 @@ def state_to_tree(state, pipeline=None) -> Dict[str, np.ndarray]:
         tree["embedding.residuals"] = np.asarray(e.residuals)
         tree["embedding.restarts"] = np.asarray(e.restarts)
         tree["embedding.converged"] = np.asarray(e.converged)
+        if e.operator_applications is not None:
+            tree["embedding.operator_applications"] = np.asarray(
+                e.operator_applications)
     if state.result is not None:
         r = state.result
         for f in ("labels", "embedding", "eigenvalues", "eig_residuals",
                   "kmeans_inertia", "lanczos_restarts", "kmeans_iterations"):
             tree[f"result.{f}"] = np.asarray(getattr(r, f))
+        if r.operator_applications is not None:
+            tree["result.operator_applications"] = np.asarray(
+                r.operator_applications)
         meta["result_reports"] = [rep.to_dict() for rep in r.reports]
     if state.reduction is not None:
         red = state.reduction
@@ -149,6 +155,11 @@ def _reports_from_meta(items) -> Tuple[StageReport, ...]:
                     attempts=d["attempts"], converged=d["converged"],
                     residual_max=d["residual_max"], wall_s=d["wall_s"])
         for d in items)
+
+
+def _optional(tree: Dict[str, np.ndarray], key: str):
+    """A field later versions added: ``None`` in an older checkpoint."""
+    return jnp.asarray(tree[key]) if key in tree else None
 
 
 def state_from_tree(tree: Dict[str, np.ndarray]):
@@ -176,7 +187,9 @@ def state_from_tree(tree: Dict[str, np.ndarray]):
             eigenvalues=jnp.asarray(tree["embedding.eigenvalues"]),
             residuals=jnp.asarray(tree["embedding.residuals"]),
             restarts=jnp.asarray(tree["embedding.restarts"]),
-            converged=jnp.asarray(tree["embedding.converged"]))
+            converged=jnp.asarray(tree["embedding.converged"]),
+            operator_applications=_optional(
+                tree, "embedding.operator_applications"))
     if "result.labels" in tree:
         kw["result"] = SpectralResult(
             labels=jnp.asarray(tree["result.labels"]),
@@ -186,7 +199,9 @@ def state_from_tree(tree: Dict[str, np.ndarray]):
             kmeans_inertia=jnp.asarray(tree["result.kmeans_inertia"]),
             lanczos_restarts=jnp.asarray(tree["result.lanczos_restarts"]),
             kmeans_iterations=jnp.asarray(tree["result.kmeans_iterations"]),
-            reports=_reports_from_meta(meta.get("result_reports", [])))
+            reports=_reports_from_meta(meta.get("result_reports", [])),
+            operator_applications=_optional(
+                tree, "result.operator_applications"))
     if "reduction.fine.deg" in tree:
         prolong = (jnp.asarray(tree["reduction.prolong"])
                    if "reduction.prolong" in tree else None)

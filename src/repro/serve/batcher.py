@@ -19,8 +19,21 @@ The padded-batch contract (tests/test_serving.py pins it):
 Latency is bounded by the **max-wait flush**: a batch goes out when it is
 full *or* when its oldest request has waited ``max_wait_s``, whichever
 comes first — p99 ≈ max_wait_s + one model call, even at low arrival
-rates.  ``benchmarks/bench_serving.py`` drives a Poisson trace through
-this exact code path and reports the p50/p99 the contract buys.
+rates.  The chip benchmark's ``dti.serve`` cell (``bench/``) drives a
+Poisson trace through this exact code path and reports the p95 latency
+and the labels per second the contract buys.
+
+The flush thread marks its states with ``jax.profiler.TraceAnnotation``
+spans, which land on the profiler's clock beside the device's operations
+and cost about two microseconds each while no profiler runs:
+``batcher.idle`` (queue empty), ``batcher.fill_wait`` (a request queued,
+waiting for fill or the max wait) and ``batcher.flush`` (batch taken to
+futures resolved) with its children ``batcher.assemble``,
+``batcher.call``, ``batcher.to_host`` and ``batcher.resolve``.  A flush
+and its children carry its running id (``flush``); the flush carries
+``requests``, ``rows``, ``full`` and the requests' waits from enqueue to
+take on the batcher's monotonic clock (``wait_us_sum``,
+``wait_us_max``).  DESIGN.md §18 lists every span.
 
 Failure isolation follows the PR 8 serve-loop contract: an exception in
 the serving function fails the futures of that flush only; the batcher
@@ -36,6 +49,7 @@ from typing import Any, Callable, List, Optional, Tuple
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 Array = jax.Array
 
@@ -74,6 +88,7 @@ class BatcherStats:
     timed_flushes: int = 0  # batch went out on the max-wait deadline
     failed_batches: int = 0  # serving-fn exceptions (futures got the error)
     split_requests: int = 0  # oversized requests split across flushes
+    requests: int = 0  # requests (split chunks counted apart) served
 
     @property
     def fill(self) -> float:
@@ -82,12 +97,12 @@ class BatcherStats:
 
 
 class _Pending:
-    __slots__ = ("rows", "future", "t0")
+    __slots__ = ("rows", "future", "t0_ns")
 
-    def __init__(self, rows: np.ndarray, future: Future, t0: float):
+    def __init__(self, rows: np.ndarray, future: Future, t0_ns: int):
         self.rows = rows
         self.future = future
-        self.t0 = t0
+        self.t0_ns = t0_ns  # enqueue time, time.monotonic_ns()
 
 
 class MicroBatcher:
@@ -117,6 +132,7 @@ class MicroBatcher:
         self._queue: List[_Pending] = []
         self._queued_rows = 0
         self._closed = False
+        self._flushes = 0  # running flush id (flush thread only)
         self._thread = threading.Thread(
             target=self._loop, name="micro-batcher", daemon=True)
         self._thread.start()
@@ -151,7 +167,7 @@ class MicroBatcher:
         with self._cond:
             if self._closed:
                 raise RuntimeError("MicroBatcher is closed")
-            self._queue.append(_Pending(rows, fut, time.monotonic()))
+            self._queue.append(_Pending(rows, fut, time.monotonic_ns()))
             self._queued_rows += rows.shape[0]
             self._cond.notify_all()
         return fut
@@ -238,48 +254,64 @@ class MicroBatcher:
         cfg = self.config
         while True:
             with self._cond:
-                while not self._queue and not self._closed:
-                    self._cond.wait()
+                if not self._queue and not self._closed:
+                    with TraceAnnotation("batcher.idle"):
+                        while not self._queue and not self._closed:
+                            self._cond.wait()
                 if not self._queue and self._closed:
                     return
                 # wait for fill or the oldest request's deadline
-                deadline = self._queue[0].t0 + cfg.max_wait_s
-                while (self._queued_rows < cfg.batch_size
-                       and not self._closed):
-                    remaining = deadline - time.monotonic()
-                    if remaining <= 0:
-                        break
-                    self._cond.wait(timeout=remaining)
+                with TraceAnnotation("batcher.fill_wait"):
+                    deadline = self._queue[0].t0_ns * 1e-9 + cfg.max_wait_s
+                    while (self._queued_rows < cfg.batch_size
+                           and not self._closed):
+                        remaining = deadline - time.monotonic()
+                        if remaining <= 0:
+                            break
+                        self._cond.wait(timeout=remaining)
                 took, rows, full = self._take_batch_locked()
+                taken_ns = time.monotonic_ns()
                 fn = self._fn
             if not took:
                 continue
-            self._flush(fn, took, rows, full)
+            self._flush(fn, took, rows, full, taken_ns)
 
-    def _flush(self, fn, took: List[_Pending], rows: int, full: bool) -> None:
+    def _flush(self, fn, took: List[_Pending], rows: int, full: bool,
+               taken_ns: int) -> None:
         cfg = self.config
-        batch = np.zeros((cfg.batch_size, self.d), np.float32)
-        off = 0
-        offsets = []
-        for p in took:
-            m = p.rows.shape[0]
-            batch[off:off + m] = p.rows
-            offsets.append((off, m))
-            off += m
-        try:
-            out = fn(batch)
-            out = jax.tree.map(np.asarray, out)  # one host sync per flush
-        except Exception as e:  # isolation: this flush fails, thread lives
-            self.stats.failed_batches += 1
-            for p in took:
-                p.future.set_exception(e)
-            return
-        self.stats.batches += 1
-        self.stats.rows += rows
-        self.stats.pad_rows += cfg.batch_size - rows
-        if full:
-            self.stats.full_flushes += 1
-        else:
-            self.stats.timed_flushes += 1
-        for p, (o, m) in zip(took, offsets):
-            p.future.set_result(jax.tree.map(lambda a: a[o:o + m], out))
+        fid = self._flushes
+        self._flushes += 1
+        waits = [(taken_ns - p.t0_ns) // 1000 for p in took]
+        with TraceAnnotation("batcher.flush", flush=fid, requests=len(took),
+                             rows=rows, full=int(full),
+                             wait_us_sum=sum(waits), wait_us_max=max(waits)):
+            with TraceAnnotation("batcher.assemble", flush=fid):
+                batch = np.zeros((cfg.batch_size, self.d), np.float32)
+                off = 0
+                offsets = []
+                for p in took:
+                    m = p.rows.shape[0]
+                    batch[off:off + m] = p.rows
+                    offsets.append((off, m))
+                    off += m
+            try:
+                with TraceAnnotation("batcher.call", flush=fid):
+                    out = fn(batch)
+                with TraceAnnotation("batcher.to_host", flush=fid):
+                    out = jax.tree.map(np.asarray, out)  # one host sync per flush
+            except Exception as e:  # isolation: this flush fails, thread lives
+                self.stats.failed_batches += 1
+                for p in took:
+                    p.future.set_exception(e)
+                return
+            self.stats.batches += 1
+            self.stats.requests += len(took)
+            self.stats.rows += rows
+            self.stats.pad_rows += cfg.batch_size - rows
+            if full:
+                self.stats.full_flushes += 1
+            else:
+                self.stats.timed_flushes += 1
+            with TraceAnnotation("batcher.resolve", flush=fid):
+                for p, (o, m) in zip(took, offsets):
+                    p.future.set_result(jax.tree.map(lambda a: a[o:o + m], out))

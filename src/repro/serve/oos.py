@@ -269,31 +269,34 @@ def oos_embed(index: ServingIndex, queries: Array):
     """
     cfg = index.config
     qf = queries.astype(jnp.float32)
-    if cfg.method == "lsh":
-        dist2, idx = _lsh_neighbors(index, qf)
-    else:
-        dist2, idx = knn_topk(
-            index.points, cfg.knn_k, queries=qf,
-            query_offset=index.n_points, impl=cfg.impl,
-            **({"block_q": cfg.block_q} if cfg.block_q else {}),
-            interpret=cfg.interpret)
-    valid = idx >= 0
-    w = jnp.where(valid,
-                  jnp.exp(-jnp.where(valid, dist2, 0.0)
-                          / (2.0 * cfg.sigma ** 2)),
-                  0.0)  # [q, k]
-    rows = index.embedding[jnp.maximum(idx, 0)]  # [q, k, ke]
-    num = jnp.einsum("qk,qke->qe", w, rows)
-    wsum = w.sum(axis=1)
-    # zero-coverage guard via where, NOT tiny-ε clamps: XLA fuses the two
-    # divisions into num / (clamp(wsum)·clamp(norm)), and ε·ε underflows to
-    # a flushed subnormal → 0/0 = NaN under jit.  where keeps the divisor
-    # exactly 1 for uncovered rows (h stays the zero row) while a genuinely
-    # NaN query still propagates (NaN > 0 is False, but num is already NaN
-    # — the post-hoc serving gate relies on that).
-    h = num / jnp.where(wsum > 0, wsum, 1.0)[:, None]
-    norm2 = jnp.sum(h * h, axis=1, keepdims=True)
-    h = h / jnp.sqrt(jnp.where(norm2 > 0, norm2, 1.0))
+    with jax.named_scope("oos_knn"):
+        if cfg.method == "lsh":
+            dist2, idx = _lsh_neighbors(index, qf)
+        else:
+            dist2, idx = knn_topk(
+                index.points, cfg.knn_k, queries=qf,
+                query_offset=index.n_points, impl=cfg.impl,
+                **({"block_q": cfg.block_q} if cfg.block_q else {}),
+                interpret=cfg.interpret)
+    with jax.named_scope("oos_interpolate"):
+        valid = idx >= 0
+        w = jnp.where(valid,
+                      jnp.exp(-jnp.where(valid, dist2, 0.0)
+                              / (2.0 * cfg.sigma ** 2)),
+                      0.0)  # [q, k]
+        rows = index.embedding[jnp.maximum(idx, 0)]  # [q, k, ke]
+        num = jnp.einsum("qk,qke->qe", w, rows)
+        wsum = w.sum(axis=1)
+        # zero-coverage guard via where, NOT tiny-ε clamps: XLA fuses the
+        # two divisions into num / (clamp(wsum)·clamp(norm)), and ε·ε
+        # underflows to a flushed subnormal → 0/0 = NaN under jit.  where
+        # keeps the divisor exactly 1 for uncovered rows (h stays the zero
+        # row) while a genuinely NaN query still propagates (NaN > 0 is
+        # False, but num is already NaN — the post-hoc serving gate relies
+        # on that).
+        h = num / jnp.where(wsum > 0, wsum, 1.0)[:, None]
+        norm2 = jnp.sum(h * h, axis=1, keepdims=True)
+        h = h / jnp.sqrt(jnp.where(norm2 > 0, norm2, 1.0))
     return h, wsum, idx
 
 
@@ -306,7 +309,8 @@ def oos_labels(index: ServingIndex, queries: Array) -> OOSResult:
     ride along (the batcher's contract).
     """
     h, wsum, idx = oos_embed(index, queries)
-    labels, dmin = km.assign_ref(h, index.centroids)
+    with jax.named_scope("oos_assign"):
+        labels, dmin = km.assign_ref(h, index.centroids)
     return OOSResult(labels=labels, dist2=dmin, embedding=h,
                      weight_sum=wsum, neighbors=idx)
 
