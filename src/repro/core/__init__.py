@@ -34,6 +34,7 @@ from repro.core.operator import (  # noqa: F401
     CooOperator,
     LinearOperator,
     ShardedCooOperator,
+    TiledCooOperator,
 )
 from repro.core.pipeline import (  # noqa: F401  (deprecated shims)
     SpectralClusteringConfig,

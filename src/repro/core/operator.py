@@ -89,6 +89,58 @@ jax.tree_util.register_dataclass(CooOperator, ["a"], ["mesh"])
 
 
 @dataclasses.dataclass(frozen=True)
+class TiledCooOperator:
+    """A row-sorted COO whose single-vector product is the ``coo_spmv``
+    Pallas kernel over its chunked layout (DESIGN.md §19): ``x`` held in
+    VMEM, gathered in registers, rows reduced in the kernel.  ``mm`` keeps
+    the segment-sum path of :class:`CooOperator`.  Build it with
+    :meth:`build`, outside the product (the layout is built once, on the
+    device); ``impl``/``interpret`` mirror the ``coo_spmv`` wrapper's
+    knobs."""
+
+    a: COO
+    tiles: Any  # repro.kernels.coo_spmv.CooTiles
+    impl: str = "auto"  # "auto" | "pallas" | "ref"
+    interpret: Optional[bool] = None
+    mesh: Any = None
+
+    @classmethod
+    def build(cls, a: COO, **knobs) -> "TiledCooOperator":
+        from repro.kernels.coo_spmv import build_tiles
+        from repro.sparse.ops import sort_coo_rows
+
+        s = sort_coo_rows(a)
+        return cls(a, build_tiles(s.row, s.col, s.val, a.shape[0]), **knobs)
+
+    @property
+    def shape(self) -> Tuple[int, int]:
+        return self.a.shape
+
+    @property
+    def dtype(self):
+        return self.a.val.dtype
+
+    @property
+    def nnz(self) -> int:
+        # the layout's slots, padding included: the static bound of what
+        # one product streams (the kernel runs the chunks the rows use)
+        return self.tiles.slots
+
+    def mv(self, x: Array) -> Array:
+        from repro.kernels.coo_spmv import coo_spmv
+
+        return coo_spmv(self.tiles, x, impl=self.impl,
+                        interpret=self.interpret)
+
+    def mm(self, x: Array) -> Array:
+        return spmm_coo(self.a, x)
+
+
+jax.tree_util.register_dataclass(TiledCooOperator, ["a", "tiles"],
+                                 ["impl", "interpret", "mesh"])
+
+
+@dataclasses.dataclass(frozen=True)
 class BlockEllOperator:
     """BlockELL(+COO tail) operator: dense strided ELL-body loads, with the
     multi-vector ``mm`` going through the ``ell_spmm`` wrapper, which runs

@@ -52,7 +52,8 @@ import repro.core.kmeans as km
 import repro.core.lanczos as lz
 import repro.core.laplacian as lap
 from repro.core.health import HealthConfig, PipelineError, StageReport
-from repro.core.operator import CooOperator, LinearOperator, ShardedCooOperator
+from repro.core.operator import (CooOperator, LinearOperator,
+                                 ShardedCooOperator, TiledCooOperator)
 from repro.core.reduce import (
     CoarsenConfig,
     ReduceInfo,
@@ -65,6 +66,7 @@ from repro.kernels.lsh_candidates.ops import (
     MAX_N_BITS as _MAX_LSH_BITS,
 )
 from repro.core.similarity import build_knn_graph, graph_from_knn
+from repro.kernels.coo_spmv import kernel_applies
 from repro.sparse.distributed import (
     ShardedCOO,
     auto_mesh,
@@ -565,6 +567,12 @@ class SpectralPipeline:
         ``ell_spmm`` kernel.  Conversion needs concrete arrays — under a jit
         trace it falls back to the COO operator with a warning (build the
         state eagerly, or pass ``operator=`` into :meth:`embed`).
+
+        On a TPU, single-vector Lanczos on one device gets the
+        :class:`~repro.core.operator.TiledCooOperator` (the ``coo_spmv``
+        kernel, DESIGN.md §19) for graphs of up to
+        ``repro.kernels.coo_spmv.ops.MAX_N`` nodes; its layout is built
+        here, on the device, and the stage report notes the path.
         """
         return self._operator_with_notes(state)[0]
 
@@ -599,7 +607,15 @@ class SpectralPipeline:
                     "operator= to embed()",
                     RuntimeWarning, stacklevel=3)
                 return CooOperator(state.adj), ("blockell_to_coo_fallback",)
-        return CooOperator(state.adj), ()
+        adj = state.adj
+        if (self.plan.device == "single" and self.eig.solver == "lanczos"
+                and self.eig.block_size == 1 and kernel_applies(adj.shape[0])):
+            # Lanczos's single-vector products run the coo_spmv kernel; its
+            # layout is built here, once, outside the product
+            with jax.named_scope("stage2"):
+                op = TiledCooOperator.build(adj)
+            return op, (f"coo_spmv[nnz={adj.nnz},slots={op.nnz}]",)
+        return CooOperator(adj), ()
 
     # -- Stage 1 ------------------------------------------------------------
 

@@ -13,7 +13,10 @@ Kernels:
                   approximate Stage-1 front-end; candidates feed the exact
                   knn_topk_rerank, O(n²d) → O(n·m·d)).
   kmeans_assign — fused pairwise-distance + online argmin (Stage 3 hot op).
-  ell_spmv      — blocked-ELL SpMV (Stage 2 hot op, single vector).
+  coo_spmv      — row-sorted COO SpMV in one pass: x in VMEM, gathered
+                  in registers, rows reduced in the kernel (Stage 2 hot op,
+                  single vector; DESIGN.md §19).
+  ell_spmv      — blocked-ELL SpMV (Stage 2, single vector).
   ell_spmm      — blocked-ELL multi-vector SpMM (Stage 2 hot op in block-
                   Lanczos mode: one nnz stream serves b Krylov vectors).
 """

@@ -1,8 +1,9 @@
 """Sparse linear-algebra ops on the formats in :mod:`repro.sparse.formats`.
 
-These are the jnp reference paths (pure JAX, shardable, differentiable).  The
-Pallas BlockELL kernel in :mod:`repro.kernels.ell_spmv` accelerates the same
-contract on TPU; ``repro.sparse.distributed`` wraps them in shard_map.
+These are the jnp reference paths (pure JAX, shardable, differentiable).  On
+a TPU, Stage 2's single-vector product runs the Pallas kernel of
+:mod:`repro.kernels.coo_spmv` over a chunked layout of the same row-sorted
+COO; ``repro.sparse.distributed`` wraps these paths in shard_map.
 """
 from __future__ import annotations
 

@@ -4,8 +4,9 @@ Nothing runs: the TPU compiler, which is installed without a chip, compiles
 each kernel at the DTI widths for one chip of a ``v5e:2x2`` topology and
 raises what the chip's compiler would raise (tile alignment, scoped-VMEM
 limits) — what interpret-mode tests cannot see.  ``ell_spmm``/``ell_spmv``
-are not here: Mosaic refuses their in-kernel gather, so a TPU runs their XLA
-path (tests/test_kernels_ell_spmm.py checks that refusal).
+are not here: Mosaic refuses their gather over all of ``x``, so a TPU runs
+their XLA path (tests/test_kernels_ell_spmm.py checks that refusal);
+``coo_spmv`` gathers within one vreg, which Mosaic compiles.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library at a time, and the test workers all import
@@ -96,6 +97,21 @@ def test_lsh_hash_codes_compile(one_chip):
              one_chip, ((4096, 90), F32), ((16, 90, 17), F32))
 
 
+@pytest.mark.parametrize("n,nnz", [(28508, 912256), (20000, 1546776),
+                                   (142541, 4561312)],
+                         ids=["dti", "syn200", "dti_full"])
+def test_coo_spmv_compiles(one_chip, n, nnz):
+    """Stage 2's single-vector product at the deployments' graph sizes,
+    with the layout it is built from: their shapes follow from n and nnz
+    alone."""
+    from repro.kernels.coo_spmv import build_tiles, coo_spmv
+
+    _compile(lambda r, c, v, x: coo_spmv(build_tiles(r, c, v, n), x,
+                                         impl="pallas", interpret=False),
+             one_chip, ((nnz,), I32), ((nnz,), I32), ((nnz,), F32),
+             ((n,), F32))
+
+
 @pytest.mark.parametrize("exchange", [None, "gather", "ring"],
                          ids=["one_chip", "four_chips_gather",
                               "four_chips_ring"])
@@ -129,3 +145,7 @@ def test_dti_pipeline_compiles(topo, one_chip, monkeypatch, exchange):
     finally:
         jax.clear_caches()
     assert text.count("tpu_custom_call") >= 2  # knn_topk and kmeans_iter
+    if exchange is None:  # one device: Lanczos's products run coo_spmv
+        calls = [line for line in text.splitlines()
+                 if "tpu_custom_call" in line and "/spmv/" in line]
+        assert calls, "no Pallas kernel under the spmv scope"
