@@ -27,7 +27,6 @@ the [n, R] filtered block happens once, outside the recurrence).
 """
 from __future__ import annotations
 
-import math
 from functools import partial
 from typing import Optional
 
@@ -53,7 +52,9 @@ from repro.kernels.lsh_candidates.ops import (
 from repro.sparse.distributed import (  # noqa: F401  (normalize_sharded re-export)
     ShardedCOO,
     auto_mesh,
+    axis_tuple,
     normalize_sharded,
+    num_shards,
     padded_rows,
     ring_shift,
 )
@@ -61,14 +62,6 @@ from repro.sparse.distributed import (  # noqa: F401  (normalize_sharded re-expo
 Array = jax.Array
 
 _EXCHANGES = ("gather", "ring")
-
-
-def _axis_tuple(axis) -> tuple:
-    return (axis,) if isinstance(axis, str) else tuple(axis)
-
-
-def _axis_size(mesh, axis) -> int:
-    return math.prod(mesh.shape[a] for a in _axis_tuple(axis))
 
 
 def merge_topk(best_d: Array, best_i: Array, new_d: Array, new_i: Array,
@@ -151,7 +144,7 @@ def make_knn_rowblock(mesh, k: int, *, axis: str = "data", block_q: int = 1024,
             f"{exchange!r}")
     mesh = auto_mesh(mesh)
     m = default_candidates(k, n_tables) if candidates is None else candidates
-    n_shards = _axis_size(mesh, axis)
+    n_shards = num_shards(mesh, axis)
 
     @partial(
         _shard_map,
@@ -326,10 +319,10 @@ def kmeans_sharded(
         raise ValueError("KMeansConfig.k is unset — standalone kmeans_sharded "
                          "needs an explicit k (use cfg.resolved(k))")
     mesh = auto_mesh(mesh)
-    axes = _axis_tuple(axis)
+    axes = axis_tuple(axis)
     n, d = x.shape
     k = cfg.k
-    n_shards = _axis_size(mesh, axes)
+    n_shards = num_shards(mesh, axes)
     n_pad = padded_rows(n, n_shards)
     if cfg.empty == "reseed_farthest" and n_pad // n_shards < k:
         raise ValueError(
@@ -357,7 +350,7 @@ def kmeans_sharded(
             # row-major like the row partitioning itself
             idx = jnp.zeros((), jnp.int32)
             for a in axes:
-                idx = idx * _axis_size(mesh, (a,)) + jax.lax.axis_index(a)
+                idx = idx * num_shards(mesh, (a,)) + jax.lax.axis_index(a)
             return idx
 
         def global_farthest(dmin):

@@ -224,6 +224,28 @@ def scoped(name: str, fn: Callable) -> Callable:
     return call
 
 
+def _on_rows(a: Array, rows, dim: int) -> Array:
+    """``a`` with its axis ``dim`` (length n) split over the chips as the
+    sharding ``rows`` splits an [n] vector, or unchanged where ``rows`` is
+    None: a row-sharded operator keeps the Krylov vectors on the chips
+    that own their rows, so each Gram-Schmidt product is a local GEMV and
+    one all-reduce of m + 1 couplings (DESIGN.md §20).  Where the chips do
+    not divide n, GSPMD places ``a`` (an eager placement refuses uneven
+    blocks)."""
+    if rows is None:
+        return a
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.sparse.distributed import num_shards
+
+    if a.shape[dim] % num_shards(rows.mesh, rows.spec[0]):
+        return a
+    spec = [None] * a.ndim
+    spec[dim] = rows.spec[0]
+    return jax.lax.with_sharding_constraint(
+        a, NamedSharding(rows.mesh, P(*spec)))
+
+
 def _orthonormal_against(v: Array, basis: Array, key: Array) -> Array:
     """Random unit vector orthogonal to the (zero-padded) basis rows —
     invariant-subspace escape hatch (ARPACK does the same on breakdown)."""
@@ -249,6 +271,10 @@ def eigsh(op, cfg, *, v0: Optional[Array] = None,
     (:func:`repro.core.chebyshev.chebyshev_eigsh`) — same operator contract,
     same :class:`LanczosResult` out.
 
+    An operator with a ``row_sharding`` (a row-sharded operator) has the
+    single-vector solver keep the Krylov basis split by rows as its
+    products are.
+
     Every matmul the solvers trace runs at full fp32.  A TPU's default is
     one bf16 pass, which leaves Gram-Schmidt ~1e-3 short of orthogonal: on
     near-degenerate spectra (k separated blobs) Lanczos then stalls above
@@ -263,7 +289,8 @@ def eigsh(op, cfg, *, v0: Optional[Array] = None,
         validate_basis(cfg, n)
         if cfg.block_size > 1:
             return _lanczos_topk_block(op.mm, n, cfg, v0=v0, key=key)
-        return _lanczos_topk_single(op.mv, n, cfg, v0=v0, key=key)
+        return _lanczos_topk_single(op.mv, n, cfg, v0=v0, key=key,
+                                    rows=getattr(op, "row_sharding", None))
 
 
 def lanczos_topk(
@@ -298,8 +325,11 @@ def _lanczos_topk_single(
     *,
     v0: Optional[Array] = None,
     key: Optional[Array] = None,
+    rows=None,
 ) -> LanczosResult:
-    """Single-vector thick-restart Lanczos (the ``block_size=1`` engine)."""
+    """Single-vector thick-restart Lanczos (the ``block_size=1`` engine).
+    ``rows``: the sharding of the operator's rows, which the basis follows
+    (:func:`_on_rows`)."""
     assert matvec is not None, "need matvec for block_size=1"
     matvec = scoped("spmv", matvec)
     k, m = cfg.k, cfg.m
@@ -309,7 +339,7 @@ def _lanczos_topk_single(
 
     if v0 is None:
         v0 = jax.random.normal(key, (n,), f32)
-    v0 = v0.astype(f32)
+    v0 = _on_rows(v0.astype(f32), rows, 0)
     v0 = v0 / jnp.maximum(jnp.linalg.norm(v0), 1e-30)
 
     sign = 1.0 if cfg.which == "LA" else -1.0  # "SA" negates the spectrum
@@ -330,7 +360,7 @@ def _lanczos_topk_single(
             v_next = jnp.where(
                 beta > 1e-10, w / jnp.maximum(beta, 1e-30),
                 _orthonormal_against(w, V, sub))
-            V = V.at[j + 1].set(v_next)
+            V = _on_rows(V.at[j + 1].set(v_next), rows, 1)
             T = T.at[j + 1, j].set(beta)
             T = T.at[j, j + 1].set(beta)
         return V, T, key, apps + 1
@@ -353,7 +383,7 @@ def _lanczos_topk_single(
             Y = (S[:, keep].T @ V[:m]).astype(f32)  # [l_keep, n] Ritz vectors
             V_new = jnp.zeros_like(V)
             V_new = V_new.at[:l_keep].set(Y)
-            V_new = V_new.at[l_keep].set(V[m])
+            V_new = _on_rows(V_new.at[l_keep].set(V[m]), rows, 1)
             h = beta_m * S[m - 1, keep]
             T_new = jnp.zeros_like(T)
             T_new = T_new.at[jnp.arange(l_keep), jnp.arange(l_keep)].set(
@@ -362,7 +392,7 @@ def _lanczos_topk_single(
             T_new = T_new.at[:l_keep, l_keep].set(h)
         return (V_new, T_new, key, theta, S, V, res, apps), n_conv, l_keep
 
-    V0 = jnp.zeros((m + 1, n), f32).at[0].set(v0)
+    V0 = _on_rows(jnp.zeros((m + 1, n), f32).at[0].set(v0), rows, 1)
     T0 = jnp.zeros((m + 1, m + 1), f32)
 
     l_keep_static = restart_keep_size(cfg)
@@ -410,7 +440,7 @@ def _lanczos_topk_single(
         topk = slice(m - k, m)
         vals = theta[topk][::-1] * sign  # descending, undo "SA" negation
         U = (S[:, topk].T @ V_old[:m]).astype(cfg.dtype)  # [k, n]
-        U = U[::-1].T  # [n, k] descending order
+        U = _on_rows(U[::-1].T, rows, 0)  # [n, k] descending order
         res_k = res[topk][::-1]
     return LanczosResult(
         eigenvalues=vals.astype(cfg.dtype),

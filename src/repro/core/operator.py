@@ -141,6 +141,78 @@ jax.tree_util.register_dataclass(TiledCooOperator, ["a", "tiles"],
 
 
 @dataclasses.dataclass(frozen=True)
+class RowTiledCooOperator:
+    """:class:`TiledCooOperator` with its rows split in equal blocks over a
+    mesh axis (DESIGN.md §20).  Each chip holds its own block in the
+    ``coo_spmv`` layout, built on the chips from the whole row-sorted COO
+    (:func:`repro.sparse.distributed.build_row_tiles`); ``mv`` is one
+    shard_map: one all-gather of ``x``, then the kernel over the chip's
+    own chunks (:func:`repro.sparse.distributed.tiled_spmv`).  Vectors are
+    [n], sharded by rows (``row_sharding``); where the chips do not divide
+    n, ``mv`` pads ``x`` with zeros and cuts ``y`` back, so the padding
+    rows never enter the caller's vectors.  ``mm`` keeps the segment-sum
+    path of :class:`CooOperator`."""
+
+    a: COO
+    tiles: Any  # repro.kernels.coo_spmv.CooTiles, one block per chip
+    mesh: Any
+    axis: Any = "data"
+    impl: str = "auto"  # "auto" | "pallas" | "ref"
+    interpret: Optional[bool] = None
+
+    @classmethod
+    def build(cls, a: COO, mesh, axis="data", **knobs) -> "RowTiledCooOperator":
+        from repro.sparse.distributed import auto_mesh, build_row_tiles
+        from repro.sparse.ops import sort_coo_rows
+
+        mesh = auto_mesh(mesh)
+        return cls(a, build_row_tiles(mesh, sort_coo_rows(a), axis=axis),
+                   mesh, axis, **knobs)
+
+    @property
+    def shape(self) -> Tuple[int, int]:
+        return self.a.shape
+
+    @property
+    def dtype(self):
+        return self.a.val.dtype
+
+    @property
+    def nnz(self) -> int:
+        # one chip's slots, padding included: each chip's layout is sized
+        # to hold all the nonzeros, and the chips' blocks split them, so a
+        # product streams about this many over all chips, not S times it
+        return self.tiles.slots // self.shards
+
+    @property
+    def shards(self) -> int:
+        return self.tiles.cols.shape[0]
+
+    @property
+    def row_sharding(self):
+        """Where an [n] vector's rows live: split over the mesh axis."""
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        return NamedSharding(self.mesh, P(self.axis))
+
+    def mv(self, x: Array) -> Array:
+        from repro.sparse.distributed import tiled_spmv
+
+        n, pad = self.shape[0], self.shards * self.tiles.rows - self.shape[0]
+        y = tiled_spmv(self.mesh, self.tiles, jnp.pad(x, (0, pad)) if pad
+                       else x, axis=self.axis, impl=self.impl,
+                       interpret=self.interpret)
+        return y[:n] if pad else y
+
+    def mm(self, x: Array) -> Array:
+        return spmm_coo(self.a, x)
+
+
+jax.tree_util.register_dataclass(RowTiledCooOperator, ["a", "tiles"],
+                                 ["mesh", "axis", "impl", "interpret"])
+
+
+@dataclasses.dataclass(frozen=True)
 class BlockEllOperator:
     """BlockELL(+COO tail) operator: dense strided ELL-body loads, with the
     multi-vector ``mm`` going through the ``ell_spmm`` wrapper, which runs

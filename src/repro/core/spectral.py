@@ -53,7 +53,8 @@ import repro.core.lanczos as lz
 import repro.core.laplacian as lap
 from repro.core.health import HealthConfig, PipelineError, StageReport
 from repro.core.operator import (CooOperator, LinearOperator,
-                                 ShardedCooOperator, TiledCooOperator)
+                                 RowTiledCooOperator, ShardedCooOperator,
+                                 TiledCooOperator)
 from repro.core.reduce import (
     CoarsenConfig,
     ReduceInfo,
@@ -261,7 +262,9 @@ class Plan:
     device        "single" (default) or "sharded".  A ShardedCOO input always
                   runs the sharded Stage 2-3 regardless (its layout implies
                   the mesh); ``device="sharded"`` additionally row-block-
-                  shards Stage 1 for raw-points inputs and enables the
+                  shards Stage 1 for raw-points inputs, on a TPU row-shards
+                  Stage 2's single-vector Lanczos over the chips on the
+                  ``coo_spmv`` kernel (DESIGN.md §20), and enables the
                   explicit-collective Stage 3 under ``variant="shard_map"``.
     mesh          jax Mesh (required for shard_map collectives and the
                   sharded Stage 1; not serialized by :meth:`to_dict`).
@@ -452,6 +455,20 @@ def _raw_weights(state: GraphState, *, host_compact: bool = False) -> COO:
     return w
 
 
+def _row_blocks_note(op: RowTiledCooOperator) -> str:
+    """The Stage-2 report's note of the row-sharded kernel path: chips,
+    rows and layout slots a chip; where the layout is concrete (an eager
+    run), the shards' balance too: the most nonzeros and chunks a chip
+    holds.  A note is static, so a jitted job's report has no balance."""
+    t, s = op.tiles, op.shards
+    note = (f"coo_spmv_rows[shards={s},rows={t.rows},nnz={op.a.nnz},"
+            f"slots={op.nnz}")
+    if health.is_concrete(t.used):
+        nnz = np.asarray((t.cols >= 0).sum(axis=(1, 2)))
+        note += f",nnz_max={int(nnz.max())},used_max={int(np.max(t.used))}"
+    return note + "]"
+
+
 # ---------------------------------------------------------------------------
 # The facade
 # ---------------------------------------------------------------------------
@@ -568,13 +585,28 @@ class SpectralPipeline:
         trace it falls back to the COO operator with a warning (build the
         state eagerly, or pass ``operator=`` into :meth:`embed`).
 
-        On a TPU, single-vector Lanczos on one device gets the
-        :class:`~repro.core.operator.TiledCooOperator` (the ``coo_spmv``
-        kernel, DESIGN.md §19) for graphs of up to
-        ``repro.kernels.coo_spmv.ops.MAX_N`` nodes; its layout is built
-        here, on the device, and the stage report notes the path.
+        On a TPU, single-vector Lanczos gets the ``coo_spmv`` kernel
+        (DESIGN.md §19) for graphs of up to
+        ``repro.kernels.coo_spmv.ops.MAX_N`` nodes: on one device the
+        :class:`~repro.core.operator.TiledCooOperator`, under a sharded plan
+        with a mesh the :class:`~repro.core.operator.RowTiledCooOperator`,
+        each chip's rows in its own layout (DESIGN.md §20).  The layout is
+        built here, on the device, and the stage report notes the path.
         """
         return self._operator_with_notes(state)[0]
+
+    def _kernel_stage2(self, n: int) -> bool:
+        """Whether Stage 2's products run the ``coo_spmv`` kernel: single-
+        vector Lanczos on a TPU, n up to ``MAX_N``."""
+        e = self.eig
+        return (e.solver == "lanczos" and e.block_size == 1
+                and kernel_applies(n))
+
+    def _rows_over_chips(self, n: int) -> bool:
+        """Whether Stage 2 runs row-sharded over the plan's mesh: the
+        kernel's path under a sharded plan with a mesh."""
+        return (self.plan.device == "sharded" and self.plan.mesh is not None
+                and self._kernel_stage2(n))
 
     def _operator_with_notes(
             self, state: GraphState) -> Tuple[LinearOperator, Tuple[str, ...]]:
@@ -608,13 +640,18 @@ class SpectralPipeline:
                     RuntimeWarning, stacklevel=3)
                 return CooOperator(state.adj), ("blockell_to_coo_fallback",)
         adj = state.adj
-        if (self.plan.device == "single" and self.eig.solver == "lanczos"
-                and self.eig.block_size == 1 and kernel_applies(adj.shape[0])):
+        if self.plan.device == "single" and self._kernel_stage2(adj.shape[0]):
             # Lanczos's single-vector products run the coo_spmv kernel; its
             # layout is built here, once, outside the product
             with jax.named_scope("stage2"):
                 op = TiledCooOperator.build(adj)
             return op, (f"coo_spmv[nnz={adj.nnz},slots={op.nnz}]",)
+        if self._rows_over_chips(adj.shape[0]):
+            # each chip builds the layout of its own rows, here, on the chips
+            with jax.named_scope("stage2"):
+                op = RowTiledCooOperator.build(adj, self.plan.mesh,
+                                               self.plan.axis)
+            return op, (_row_blocks_note(op),)
         return CooOperator(adj), ()
 
     # -- Stage 1 ------------------------------------------------------------
@@ -715,7 +752,11 @@ class SpectralPipeline:
             if ecfg.drop_first:
                 vecs = vecs[:, 1:]
                 vals = vals[1:]
-            h = lap.embed_rows(vecs, state.inv_sqrt_deg)
+            # the single-vector solver keeps a row-sharded operator's rows
+            rows = (isinstance(op, RowTiledCooOperator)
+                    and ecfg.solver == "lanczos" and ecfg.block_size == 1)
+            h = self._hand_over(lap.embed_rows(vecs, state.inv_sqrt_deg),
+                                rows_sharded=rows)
             return EmbedState(
                 embedding=h,
                 eigenvalues=lap.smallest_laplacian_eigs_from_adj(vals),
@@ -724,6 +765,23 @@ class SpectralPipeline:
                 converged=res.converged,
                 operator_applications=res.operator_applications,
             )
+
+    def _hand_over(self, h: Array, *, rows_sharded: bool) -> Array:
+        """The embedding as Stage 2 hands it to Stage 3.  Under the GSPMD
+        plan, where Stage 3 is ``kmeans_sharded`` and Stage 2's products
+        were not those of the row-sharded operator (``rows_sharded``),
+        GSPMD ran Stage 2 replicated: the embedding is pinned replicated
+        there, or the shard_map's row blocks propagate back into the
+        Lanczos reductions and reorder their sums (DESIGN.md §10).  A
+        row-sharded Stage 2 hands its row blocks over as they are."""
+        if (self.plan.variant != "gspmd" or rows_sharded
+                or not self._kmeans_sharded_dispatch(
+                    *h.shape, self.kmeans.resolved(self.n_clusters))):
+            return h
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        return jax.lax.with_sharding_constraint(
+            h, NamedSharding(self.plan.mesh, P()))
 
     # -- Stage 3 ------------------------------------------------------------
 
@@ -774,14 +832,6 @@ class SpectralPipeline:
         if self._kmeans_sharded_dispatch(*h.shape, kcfg):
             from repro.core.distributed_pipeline import kmeans_sharded
 
-            if self.plan.variant == "gspmd":
-                # GSPMD runs this plan's Stage 2 replicated; pin the
-                # embedding there, or Stage 3's row blocks propagate back
-                # into the Lanczos reductions and reorder their sums
-                from jax.sharding import NamedSharding, PartitionSpec as P
-
-                h = jax.lax.with_sharding_constraint(
-                    h, NamedSharding(self.plan.mesh, P()))
             return kmeans_sharded(h, kcfg, key, mesh=self.plan.mesh,
                                   axis=self.plan.axis)
         if (self.plan.device == "sharded" and self.plan.mesh is not None
@@ -1003,7 +1053,8 @@ class SpectralPipeline:
         u, theta, resid = red.lift_and_smooth(
             op, u0, steps=self.coarsen.refine_steps)
         emb = EmbedState(
-            embedding=lap.embed_rows(u, fine.inv_sqrt_deg),
+            embedding=self._hand_over(lap.embed_rows(u, fine.inv_sqrt_deg),
+                                      rows_sharded=False),
             eigenvalues=lap.smallest_laplacian_eigs_from_adj(theta),
             residuals=resid,
             restarts=st.embedding.restarts,

@@ -123,15 +123,18 @@ def _kernel(tile_ref, blo_ref, bhi_ref, x_ref, col_ref, val_ref, key_ref,
 
 
 def coo_spmv_pallas(used, tile_of, blo, bhi, x_table, cols, vals, keys, ends,
-                    *, interpret: bool = False):
+                    *, out_rows: int | None = None, interpret: bool = False):
     """Raw kernel entry over a built layout: ``used`` [] int32, the chunks
     to run (the grid's length, at most n_chunks; they must reach every
     tile); ``tile_of``/``blo``/``bhi`` [n_chunks] int32 (scalar prefetch:
     each chunk's tile and the inclusive range of 8-row x-table blocks its
     columns reach), ``x_table`` [n_tiles·8, 128] f32, ``cols``/``vals``
     [n_chunks·64, 128], ``keys``/``ends`` [n_chunks·8, 128] int32.
-    Returns ``y`` as an [n_tiles·8, 128] f32 table."""
+    Returns ``y`` as an [out_rows, 128] f32 table: 8 rows for each tile of
+    the layout, as many as the x table has for a square matrix (the
+    default), fewer for a block of rows."""
     table_rows = x_table.shape[0]
+    out_rows = table_rows if out_rows is None else out_rows
     chunk = VREGS * SUBLANES
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
@@ -149,7 +152,7 @@ def coo_spmv_pallas(used, tile_of, blo, bhi, x_table, cols, vals, keys, ends,
     return pl.pallas_call(
         _kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((table_rows, LANES), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((out_rows, LANES), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,

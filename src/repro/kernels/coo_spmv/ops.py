@@ -12,6 +12,12 @@ takes ``ceil(d / 8)`` lanes, so a section of m nonzeros takes at most
 sections take at most ``ceil((nnz + 14 n) / 8192) + 2 n_tiles`` chunks, the
 static count.  The kernel's grid runs the chunks the sections use.
 
+A layout may hold a block of rows ``[r0, r0 + rows)`` of an n-column
+matrix, as a chip of a row-sharded Stage 2 holds its own (DESIGN.md §20):
+its tiles are the block's, "near" is measured from each nonzero's global
+row, and the nonzeros of other rows are left out.  The whole matrix is the
+block with ``r0 = 0`` and ``rows = n``.
+
 :func:`coo_spmv` picks its engine as the other kernel packages do: the
 kernel on a TPU, interpret mode where asked, the layout's jnp reference
 otherwise.  :func:`kernel_applies` is the dispatch rule the pipeline's
@@ -88,7 +94,7 @@ class CooTiles:
     ``tile_of``, ``blo``, ``bhi`` [n_chunks]: each chunk's tile and the
     inclusive range of 1024-column blocks its columns reach (``blo > bhi``:
     empty).  ``used`` []: the chunks the sections take, those the kernel
-    runs."""
+    runs.  Rows and keys count from the block's first row."""
 
     cols: jax.Array
     vals: jax.Array
@@ -98,7 +104,8 @@ class CooTiles:
     blo: jax.Array
     bhi: jax.Array
     used: jax.Array
-    n: int  # static: rows (= columns)
+    n: int  # static: columns, the length of x
+    rows: int  # static: the block's rows, the length of y
 
     @property
     def slots(self) -> int:
@@ -108,7 +115,8 @@ class CooTiles:
 
 jax.tree_util.register_dataclass(
     CooTiles,
-    ["cols", "vals", "keys", "ends", "tile_of", "blo", "bhi", "used"], ["n"])
+    ["cols", "vals", "keys", "ends", "tile_of", "blo", "bhi", "used"],
+    ["n", "rows"])
 
 
 def _running(x: jax.Array, op) -> jax.Array:
@@ -127,26 +135,31 @@ def _running(x: jax.Array, op) -> jax.Array:
     return op(inner, carry[:, None]).reshape(-1)[:m]
 
 
-def build_tiles(row: jax.Array, col: jax.Array, val: jax.Array,
-                n: int) -> CooTiles:
-    """The chunked layout of an n×n COO whose ``row`` is non-decreasing.
-    Device work only (traceable under jit): a binary search for the rows'
-    starts, one scatter of the nonzeros into their slots, and otherwise
+def build_tiles(row: jax.Array, col: jax.Array, val: jax.Array, n: int, *,
+                r0=0, rows: int | None = None) -> CooTiles:
+    """The chunked layout of rows ``[r0, r0 + rows)`` (all n by default) of
+    an n-column COO whose ``row`` is non-decreasing; ``r0`` may be traced
+    (a chip's first row under ``shard_map``), ``rows`` is static.  Device
+    work only (traceable under jit): a binary search for the rows' starts,
+    one scatter of the block's nonzeros into their slots, and otherwise
     passes over the rows, lanes and chunks and running maxima along the
-    nonzeros."""
+    nonzeros.  A block's static chunk count is that of all nnz nonzeros
+    in ``rows`` rows: any block may hold them all."""
+    rows = n if rows is None else rows
     nnz = row.shape[0]
-    nt, nc = n_tiles(n), n_chunks(n, nnz)
+    nt, nc = n_tiles(rows), n_chunks(rows, nnz)
     row = row.astype(jnp.int32)
     col = col.astype(jnp.int32)
     far = jnp.abs(col // TABLE_BLOCK - row // TILE_ROWS) > NEAR_BLOCKS
     part = far.astype(jnp.int32)  # section: 0 near, 1 far
-    bounds = jnp.searchsorted(row, jnp.arange(n + 1, dtype=jnp.int32)
+    first = jnp.arange(rows + 1, dtype=jnp.int32)
+    bounds = jnp.searchsorted(row, r0 + first
                               ).astype(jnp.int32)  # each row's first nonzero
     far_cum = jnp.pad(_running(part, jnp.add), (1, 0))
     deg_far = far_cum[bounds[1:]] - far_cum[bounds[:-1]]
     deg = jnp.stack([bounds[1:] - bounds[:-1] - deg_far, deg_far], axis=1)
     lanes = (deg + SUBLANES - 1) // SUBLANES  # per row and section
-    lanes_t = jnp.pad(lanes, ((0, nt * TILE_ROWS - n), (0, 0))).reshape(
+    lanes_t = jnp.pad(lanes, ((0, nt * TILE_ROWS - rows), (0, 0))).reshape(
         nt, TILE_ROWS, 2)
     chunks_s = -(-lanes_t.sum(1) // CHUNK_LANES)  # [tile, section]
     chunks_s = chunks_s.at[:, 0].max(1).reshape(-1)  # every tile has a chunk
@@ -154,7 +167,7 @@ def build_tiles(row: jax.Array, col: jax.Array, val: jax.Array,
     # each row's first lane in each section: the section's first chunk,
     # then the rows before it; non-decreasing along the rows
     lane0 = (jnp.cumsum(lanes_t, axis=1) - lanes_t
-             + chunk0_s.reshape(nt, 1, 2) * CHUNK_LANES).reshape(-1, 2)[:n]
+             + chunk0_s.reshape(nt, 1, 2) * CHUNK_LANES).reshape(-1, 2)[:rows]
 
     def per_nonzero(v):
         """A non-decreasing per-row value at each of the row's nonzeros:
@@ -175,11 +188,14 @@ def build_tiles(row: jax.Array, col: jax.Array, val: jax.Array,
     chunk, vlane = lane // CHUNK_LANES, lane % CHUNK_LANES
     slot = ((chunk * VREGS + vlane // LANES) * SUBLANES + pos % SUBLANES) \
         * LANES + vlane % LANES
+    # other rows' nonzeros go to slots past the end, dropped
+    local = row - r0
+    slot = jnp.where((local >= 0) & (local < rows), slot, nc * CHUNK_SLOTS + e)
     packed = jnp.stack([col, jax.lax.bitcast_convert_type(
-        val.astype(jnp.float32), jnp.int32), row % TILE_ROWS], axis=1)
+        val.astype(jnp.float32), jnp.int32), local % TILE_ROWS], axis=1)
     empty = jnp.array([-1, 0, -1], jnp.int32)  # column, value bits, key
     slots = jnp.broadcast_to(empty, (nc * CHUNK_SLOTS, 3)).at[slot].set(
-        packed, unique_indices=True)
+        packed, unique_indices=True, mode="drop")
     cols = slots[:, 0].reshape(-1, LANES)
     vals = jax.lax.bitcast_convert_type(slots[:, 1], jnp.float32).reshape(
         -1, LANES)
@@ -205,19 +221,23 @@ def build_tiles(row: jax.Array, col: jax.Array, val: jax.Array,
     blo = jnp.where(block >= 0, block, bhi[:, None]).min(1)
     return CooTiles(cols, vals, keys, ends, tile_of,
                     jnp.where(bhi >= 0, blo, 0), bhi,
-                    chunks_s.sum().astype(jnp.int32), n)
+                    chunks_s.sum().astype(jnp.int32), n, rows)
 
 
 @partial(jax.jit, static_argnames=("impl", "interpret"))
 def coo_spmv(t: CooTiles, x: jax.Array, *, impl: str = "auto",
              interpret: bool | None = None) -> jax.Array:
-    """``y = A x`` over the layout, accumulated in float32, in x's dtype."""
+    """``y = A x`` over the layout, accumulated in float32, in x's dtype:
+    the layout's ``t.rows`` rows, from the first ``t.n`` entries of ``x``
+    (a longer ``x``, as a row-sharded product gathers, is cut)."""
     engine = coo_spmv_engine(impl, interpret)
+    x = x[:t.n]
     if engine == "ref":
         return coo_tiles_spmv_ref(t, x)
-    rows = n_tiles(t.n) * TILE_ROWS
-    table = jnp.pad(x.astype(jnp.float32), (0, rows - t.n)).reshape(-1, LANES)
+    table = jnp.pad(x.astype(jnp.float32),
+                    (0, n_tiles(t.n) * TILE_ROWS - t.n)).reshape(-1, LANES)
     y = coo_spmv_pallas(t.used, t.tile_of, t.blo, t.bhi, table, t.cols,
                         t.vals, t.keys, t.ends,
+                        out_rows=n_tiles(t.rows) * SUBLANES,
                         interpret=engine == "pallas-interpret")
-    return y.reshape(-1)[:t.n].astype(x.dtype)
+    return y.reshape(-1)[:t.rows].astype(x.dtype)

@@ -8,7 +8,8 @@ import jax.numpy as jnp
 
 
 def coo_tiles_spmv_ref(t, x: jax.Array) -> jax.Array:
-    """``y = A x`` over a :class:`~repro.kernels.coo_spmv.ops.CooTiles`."""
+    """``y = A x`` over a :class:`~repro.kernels.coo_spmv.ops.CooTiles`:
+    its ``t.rows`` rows, from ``x`` [t.n]."""
     nc = t.tile_of.shape[0]
     lanes = t.cols.shape[1]
     sub = t.cols.shape[0] // t.keys.shape[0]
@@ -19,5 +20,5 @@ def coo_tiles_spmv_ref(t, x: jax.Array) -> jax.Array:
     live = (keys >= 0).repeat(sub, axis=2).reshape(-1) & (cols >= 0)
     xf = x.astype(jnp.float32)
     prod = jnp.where(live, t.vals.reshape(-1) * xf[jnp.maximum(cols, 0)], 0.0)
-    y = jax.ops.segment_sum(prod, jnp.where(live, rows, t.n), t.n + 1)
-    return y[:t.n].astype(x.dtype)
+    y = jax.ops.segment_sum(prod, jnp.where(live, rows, t.rows), t.rows + 1)
+    return y[:t.rows].astype(x.dtype)

@@ -21,6 +21,12 @@ Two execution paths share this layout:
                     output — measurably worse; kept as the §Perf baseline).
 ``make_sharded_spmv`` — shard_map version exploiting locality (all-gather of
                     x only).  This is the optimized path.
+
+A third layout needs no host bucketing: :func:`build_row_tiles` builds each
+chip's block of rows of a row-sorted COO in the ``coo_spmv`` kernel's
+chunked layout on the chips, under jit, and :func:`tiled_spmv` is its
+product — one all-gather of x, then the kernel over the chip's own chunks
+(DESIGN.md §20).
 """
 from __future__ import annotations
 
@@ -33,7 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map as _shard_map
+from repro.compat import SHARD_MAP_NO_CHECK, shard_map as _shard_map
 from repro.sparse.formats import COO
 
 Array = jax.Array
@@ -186,6 +192,74 @@ def make_sharded_spmv(mesh: Mesh, sm: ShardedCOO, *, axis: str | tuple = "data",
         return y.astype(x_blk.dtype)
 
     return spmv
+
+
+# ---------------------------------------------------------------------------
+# Path 3 — row blocks in the coo_spmv layout, built on the chips
+# ---------------------------------------------------------------------------
+
+def axis_tuple(axis) -> tuple:
+    """A mesh axis name, or a tuple of them, as a tuple."""
+    return (axis,) if isinstance(axis, str) else tuple(axis)
+
+
+def num_shards(mesh: Mesh, axis="data") -> int:
+    """The shards rows are split into over ``axis`` (a name or a tuple)."""
+    return int(np.prod([mesh.shape[a] for a in axis_tuple(axis)]))
+
+
+def build_row_tiles(mesh: Mesh, m: COO, *, axis="data"):
+    """Each chip's block of ``padded_rows(n, S) / S`` consecutive rows of
+    the row-sorted COO ``m``, in the ``coo_spmv`` layout
+    (:func:`repro.kernels.coo_spmv.build_tiles`), built on the chips under
+    one shard_map from the whole COO: no host bucketing, so it runs inside
+    a jitted job on Stage 1's device output.  Every array of the returned
+    :class:`~repro.kernels.coo_spmv.CooTiles` gains a leading axis of one
+    entry per chip, sharded over ``axis``; rows past n are empty."""
+    return _build_row_tiles(m.row, m.col, m.val, mesh=auto_mesh(mesh),
+                            axes=axis_tuple(axis), n=m.shape[0])
+
+
+@partial(jax.jit, static_argnames=("mesh", "axes", "n"))
+def _build_row_tiles(row, col, val, *, mesh, axes, n):
+    from repro.kernels.coo_spmv import build_tiles
+
+    rows = padded_rows(n, num_shards(mesh, axes)) // num_shards(mesh, axes)
+
+    @partial(_shard_map, mesh=mesh, in_specs=(P(), P(), P()),
+             out_specs=P(axes), **SHARD_MAP_NO_CHECK)
+    def build(row, col, val):
+        r0 = jax.lax.axis_index(axes) * rows
+        t = build_tiles(row, col, val, n, r0=r0, rows=rows)
+        return jax.tree.map(lambda a: a[None], t)
+
+    return build(row, col, val)
+
+
+def tiled_spmv(mesh: Mesh, tiles, x: Array, *, axis="data", impl="auto",
+               interpret=None) -> Array:
+    """``y = A x`` over :func:`build_row_tiles`' layout, ``x`` and ``y``
+    [S · rows] sharded by rows over ``axis``: each chip all-gathers x
+    (scope ``spmv_gather``) and runs the ``coo_spmv`` kernel over its own
+    chunks, giving its own rows.  A Mosaic kernel runs across chips only
+    inside a shard_map."""
+    return _tiled_spmv(tiles, x, mesh=auto_mesh(mesh), axes=axis_tuple(axis),
+                       impl=impl, interpret=interpret)
+
+
+@partial(jax.jit, static_argnames=("mesh", "axes", "impl", "interpret"))
+def _tiled_spmv(tiles, x, *, mesh, axes, impl, interpret):
+    from repro.kernels.coo_spmv import coo_spmv
+
+    @partial(_shard_map, mesh=mesh, in_specs=(P(axes), P(axes)),
+             out_specs=P(axes), **SHARD_MAP_NO_CHECK)
+    def spmv(t, x_blk):
+        with jax.named_scope("spmv_gather"):
+            x_full = jax.lax.all_gather(x_blk, axes, axis=0, tiled=True)
+        t = jax.tree.map(lambda a: a[0], t)
+        return coo_spmv(t, x_full, impl=impl, interpret=interpret)
+
+    return spmv(tiles, x)
 
 
 # ---------------------------------------------------------------------------

@@ -204,3 +204,139 @@ def test_stage2_report_records_the_kernel_path(monkeypatch):
                                np.asarray(base.result.eigenvalues), atol=1e-4)
     assert int(st.result.operator_applications) == int(
         base.result.operator_applications)
+
+
+# -- row blocks: a chip's own rows of an n-column matrix (DESIGN.md §20) -----
+
+def _row_blocks_graph():
+    """n odd and not a multiple of 1024; columns anywhere in five column
+    blocks, so that tiles have far sections; the third of four row blocks
+    holds no nonzero, and every seventh row elsewhere none either."""
+    n = 5001
+    rows = -(-n // 4)
+    pool = np.setdiff1d(np.arange(n), np.arange(2 * rows, 3 * rows))
+    pool = pool[pool % 7 != 0]
+    return _random(n, 20000, rows=pool, seed=11), rows
+
+
+@pytest.mark.parametrize("impl", ["pallas", "ref"])
+@pytest.mark.parametrize("block", range(4))
+def test_row_block_product_matches_dense(block, impl):
+    """Each of four chips' blocks, the last one past n, times x against the
+    dense rows; the empty block gives zeros."""
+    a, rows = _row_blocks_graph()
+    n = a.shape[0]
+    r0 = block * rows
+    t = build_tiles(a.row, a.col, a.val, n, r0=r0, rows=rows)
+    assert (t.n, t.rows) == (n, rows)
+    x = np.random.default_rng(12).normal(size=n).astype(np.float32)
+    row, col, val = (np.asarray(v) for v in (a.row, a.col, a.val))
+    own = (row >= r0) & (row < r0 + rows)
+    want, scale = np.zeros(rows), np.zeros(rows)  # the dense rows, in f64
+    np.add.at(want, row[own] - r0, val[own] * x[col[own]].astype(np.float64))
+    np.add.at(scale, row[own] - r0, np.abs(val[own] * x[col[own]]))
+    got = np.asarray(coo_spmv(t, jnp.asarray(x), impl=impl, interpret=True))
+    # only the summation order differs: float32 rounding of |A| |x|
+    assert np.all(np.abs(got - want) <= 1e-6 * scale + 1e-7)
+    assert int((np.asarray(t.cols) >= 0).sum()) == int(own.sum())
+    far = np.abs(col[own] // 1024 - row[own] // TILE_ROWS) > 2
+    assert far.any() == (block != 2)  # a far section, but in the empty one
+    if block == 2:
+        assert not own.any() and not got.any()
+
+
+def _lattice_knn(side=12, k=16, seed=13):
+    """The DTI deployment's graph in numpy: a lattice's exact kNN by
+    (distance², id), as (K + Kᵀ) entries sorted by row, random weights."""
+    pos = np.stack(np.meshgrid(*[np.arange(side)] * 3, indexing="ij"),
+                   -1).reshape(-1, 3)
+    d2 = ((pos[:, None] - pos[None]) ** 2).sum(-1)
+    np.fill_diagonal(d2, np.iinfo(np.int64).max)
+    nb = np.argsort(d2, axis=1, kind="stable")[:, :k]
+    n = pos.shape[0]
+    row = np.concatenate([np.repeat(np.arange(n), k), nb.reshape(-1)])
+    col = np.concatenate([nb.reshape(-1), np.repeat(np.arange(n), k)])
+    return _coo(row, col, n, seed)
+
+
+def _planted(blocks=100, size=50, seed=14):
+    """The Syn200 deployment's shape in numpy: dense blocks and sparse
+    edges between them, both directions, sorted by row; n spans five
+    column blocks, so most inter-block edges are far."""
+    rng = np.random.default_rng(seed)
+    n = blocks * size
+    r, c = np.triu_indices(size, 1)
+    keep = rng.random(r.size) < 0.5
+    b = np.repeat(np.arange(blocks), keep.sum()) * size
+    ir, ic = np.tile(r[keep], blocks) + b, np.tile(c[keep], blocks) + b
+    orow, ocol = rng.integers(0, n, 4000), rng.integers(0, n, 4000)
+    row = np.concatenate([ir, ic, orow, ocol])
+    col = np.concatenate([ic, ir, ocol, orow])
+    return _coo(row, col, n, seed)
+
+
+# sha256 of the layout's arrays as the square build made them before it
+# took blocks of rows (the same graphs)
+SQUARE_LAYOUT_SHA256 = {
+    "dti_shaped":
+        "2f31bfec7e20fbecb588d02f6ba1959a991a58a1321bb36d6ddeaa2e9127850e",
+    "syn200_shaped":
+        "36a09f3eac5269880c1e66fb48730f32d4f7857c1c108e52ad96670c7641717b",
+}
+SQUARE_GRAPHS = {"dti_shaped": _lattice_knn, "syn200_shaped": _planted}
+
+
+def _layout_digest(t) -> str:
+    import hashlib
+
+    h = hashlib.sha256()
+    for f in ("cols", "vals", "keys", "ends", "tile_of", "blo", "bhi",
+              "used"):
+        h.update(np.ascontiguousarray(np.asarray(getattr(t, f))).tobytes())
+    return h.hexdigest()
+
+
+@pytest.mark.parametrize("graph", list(SQUARE_GRAPHS))
+def test_square_layout_is_bit_identical(graph):
+    """The whole matrix builds the layout it built before blocks of rows
+    existed, and so does a traced first row of 0 (a chip's offset under
+    shard_map)."""
+    a = SQUARE_GRAPHS[graph]()
+    n = a.shape[0]
+    t = build_tiles(a.row, a.col, a.val, n)
+    assert _layout_digest(t) == SQUARE_LAYOUT_SHA256[graph]
+    traced = jax.jit(lambda r0: build_tiles(a.row, a.col, a.val, n, r0=r0,
+                                            rows=n))(jnp.int32(0))
+    assert _layout_digest(traced) == _layout_digest(t)
+
+
+def test_sharded_plan_dispatch_takes_row_blocks(monkeypatch):
+    """Under a sharded plan with a mesh, the kernel's path on a TPU is the
+    row-sharded operator, noted with the chips' balance where the layout is
+    concrete; off a TPU, and for block Lanczos or Chebyshev, the
+    segment-sum operator as before."""
+    from jax.sharding import Mesh
+
+    from repro.core.operator import RowTiledCooOperator
+    from repro.core.spectral import Plan
+
+    plan = Plan(device="sharded", mesh=Mesh(np.array(jax.devices()[:1]),
+                                            ("data",)))
+    a = _sbm()
+    n = a.shape[0]
+    state = SpectralPipeline(n_clusters=12).prepare(
+        COO(a.row, a.col, a.val, a.shape))
+    pipe = SpectralPipeline(n_clusters=12, plan=plan)
+    op, notes = pipe._operator_with_notes(state)
+    assert isinstance(op, CooOperator) and notes == ()  # CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    op, notes = pipe._operator_with_notes(state)
+    assert isinstance(op, RowTiledCooOperator) and op.shape == (n, n)
+    slots = n_chunks(n, a.nnz) * CHUNK_SLOTS
+    assert notes == (f"coo_spmv_rows[shards=1,rows={n},nnz={a.nnz},"
+                     f"slots={slots},nnz_max={a.nnz},"
+                     f"used_max={int(op.tiles.used[0])}]",)
+    for eig in (EigConfig(block_size=4), EigConfig(solver="chebyshev")):
+        op, notes = SpectralPipeline(
+            n_clusters=12, eig=eig, plan=plan)._operator_with_notes(state)
+        assert isinstance(op, CooOperator) and notes == ()

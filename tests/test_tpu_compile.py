@@ -119,7 +119,8 @@ def test_dti_pipeline_compiles(topo, one_chip, monkeypatch, exchange):
     """The whole DTI program (Stage 1 kNN → Lanczos → fused k-means) at a
     small n, on one chip and sharded over four.  ``jax.default_backend`` is
     steered to "tpu" so ``impl="auto"`` takes its TPU branches, as on the
-    chip; across four chips every Mosaic kernel must sit in a shard_map."""
+    chip; across four chips every Mosaic kernel must sit in a shard_map,
+    and Stage 2's product is the kernel over each chip's rows."""
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -140,12 +141,36 @@ def test_dti_pipeline_compiles(topo, one_chip, monkeypatch, exchange):
     args = [jax.ShapeDtypeStruct(s, dt, sharding=where)
             for s, dt in (((n, 90), F32), ((n, 3), F32), ((2,), jnp.uint32))]
     try:
-        text = jax.jit(lambda x, p, key: pipe.run(x, key, points=p)).lower(
-            *args).compile().as_text()
+        traced = jax.jit(lambda x, p, key: pipe.run(x, key, points=p)).trace(
+            *args)
+        text = traced.lower().compile().as_text()
     finally:
         jax.clear_caches()
     assert text.count("tpu_custom_call") >= 2  # knn_topk and kmeans_iter
-    if exchange is None:  # one device: Lanczos's products run coo_spmv
-        calls = [line for line in text.splitlines()
-                 if "tpu_custom_call" in line and "/spmv/" in line]
-        assert calls, "no Pallas kernel under the spmv scope"
+    # Lanczos's products run coo_spmv: on one device, or on each chip's own
+    # rows after one all-gather of x (DESIGN.md §20)
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and "/spmv/" in line]
+    assert calls, "no Pallas kernel under the spmv scope"
+    if exchange is not None:
+        # the program's one all-gather of x a product, read from the traced
+        # program: the TPU compiler runs it as an all-reduce of a zero-padded
+        # x, and at this n drops its metadata doing so
+        gathers = [path for prim, path in _scoped_primitives(traced.jaxpr)
+                   if prim == "all_gather" and "/spmv/" in path
+                   and path.endswith("/spmv_gather")]
+        assert gathers, "no all-gather of x under the spmv_gather scope"
+
+
+def _scoped_primitives(jaxpr, path=""):
+    """``(primitive, scopes)`` of every equation of a traced program, its
+    scopes those of the equations that hold it followed by its own."""
+    from jax.extend import core
+
+    for eqn in getattr(jaxpr, "jaxpr", jaxpr).eqns:
+        here = f"{path}/{eqn.source_info.name_stack}"
+        yield eqn.primitive.name, here
+        for p in eqn.params.values():
+            for sub in (p if isinstance(p, (tuple, list)) else (p,)):
+                if isinstance(sub, (core.Jaxpr, core.ClosedJaxpr)):
+                    yield from _scoped_primitives(sub, here)
