@@ -38,7 +38,7 @@ import repro.core.kmeans as km
 from repro.compat import SHARD_MAP_NO_CHECK, shard_map as _shard_map
 from repro.core.pipeline import SpectralClusteringConfig
 from repro.core.spectral import GraphConfig, Plan, SpectralResult
-from repro.kernels.knn_topk.ops import knn_topk, knn_topk_rerank
+from repro.kernels.knn_topk.ops import knn_topk, knn_topk_engine, knn_topk_rerank
 from repro.kernels.lsh_candidates.ops import (
     DEFAULT_N_BITS,
     DEFAULT_N_TABLES,
@@ -128,7 +128,10 @@ def make_knn_rowblock(mesh, k: int, *, axis: str = "data", block_q: int = 1024,
     at fixed per-shard rows.
 
     Returns ``knn(x) -> (dist² [n, k], idx [n, k])`` with rows sharded over
-    ``axis``; outputs feed :func:`repro.core.similarity.graph_from_knn`.  Any
+    ``axis``; outputs feed :func:`repro.core.similarity.graph_from_knn`.
+    ``knn(x, with_tiles=True)`` appends ``knn_topk``'s int32 [2] count of
+    candidate tiles merged and visited, summed over ring steps and chips
+    (``None`` on the jnp reference and the LSH path).  Any
     n works: rows are padded to equal blocks (as
     :func:`~repro.sparse.distributed.partition_coo_by_rows` pads graphs)
     with a point farther from every real point than any two real points are
@@ -145,12 +148,14 @@ def make_knn_rowblock(mesh, k: int, *, axis: str = "data", block_q: int = 1024,
     mesh = auto_mesh(mesh)
     m = default_candidates(k, n_tables) if candidates is None else candidates
     n_shards = num_shards(mesh, axis)
+    # the kernel counts its tiles; each chip's count leaves as a row of [S, 2]
+    counted = method == "exact" and knn_topk_engine(impl, interpret) != "ref"
 
     @partial(
         _shard_map,
         mesh=mesh,
         in_specs=(P(axis, None),),
-        out_specs=(P(axis, None), P(axis, None)),
+        out_specs=(P(axis, None),) * (3 if counted else 2),
         # jax 0.4.x has no replication rule for pallas_call; outputs are all
         # explicitly sharded over `axis`, so the check adds nothing here.
         **SHARD_MAP_NO_CHECK,
@@ -168,8 +173,10 @@ def make_knn_rowblock(mesh, k: int, *, axis: str = "data", block_q: int = 1024,
                                   interpret=interpret)
             return knn_topk_rerank(x_full, cand, k, queries=x_blk,
                                    query_rows=qrows, block_q=block_q)
-        return knn_topk(x_full, k, queries=x_blk, query_offset=offset,
-                        block_q=block_q, impl=impl, interpret=interpret)
+        out = knn_topk(x_full, k, queries=x_blk, query_offset=offset,
+                       block_q=block_q, impl=impl, interpret=interpret,
+                       with_tiles=True)
+        return out[:2] + (out[2][None],) if counted else out[:2]
 
     def _knn_ring(x_blk):
         nl = x_blk.shape[0]
@@ -177,6 +184,7 @@ def make_knn_rowblock(mesh, k: int, *, axis: str = "data", block_q: int = 1024,
         my = jax.lax.axis_index(axis)
         best_d = jnp.full((nl, k), jnp.inf, jnp.float32)
         best_i = jnp.full((nl, k), -1, jnp.int32)
+        tiles = jnp.zeros((2,), jnp.int32)
         arange_l = jnp.arange(nl, dtype=jnp.int32)
         if method == "lsh":
             # hash ONCE, at home: codes/ties for the local block + the
@@ -207,30 +215,37 @@ def make_knn_rowblock(mesh, k: int, *, axis: str = "data", block_q: int = 1024,
                                            block_q=block_q)
             else:
                 blk = payload
-                d_t, i_t = knn_topk(blk, k, queries=x_blk,
-                                    query_offset=(my - src) * nl,
-                                    block_q=block_q, impl=impl,
-                                    interpret=interpret)
+                d_t, i_t, t_t = knn_topk(blk, k, queries=x_blk,
+                                         query_offset=(my - src) * nl,
+                                         block_q=block_q, impl=impl,
+                                         interpret=interpret, with_tiles=True)
+                if counted:
+                    tiles = tiles + t_t
             i_g = jnp.where(i_t >= 0, i_t + src * nl, -1)
             best_d, best_i = merge_topk(best_d, best_i,
                                         d_t.astype(jnp.float32), i_g, k)
             if t < S - 1:
                 payload = ring_shift(payload, axis, S)
-        return best_d, best_i
+        return (best_d, best_i, tiles[None]) if counted else (best_d, best_i)
 
-    def knn(x):
+    def knn(x, with_tiles: bool = False):
         n = x.shape[0]
         n_pad = padded_rows(n, n_shards)
-        if n_pad == n:
-            return search(x)
-        # |coordinates| <= M puts real pairs within d·(2M)² and pads at
-        # least d·(3M+1)² from every real point
-        far = 4.0 * jnp.max(jnp.abs(x.astype(jnp.float32))) + 1.0
-        dist2, idx = search(jnp.concatenate(
-            [x, jnp.full((n_pad - n, x.shape[1]), far, x.dtype)]))
-        pad_hit = idx[:n] >= n
-        return (jnp.where(pad_hit, jnp.inf, dist2[:n]),
-                jnp.where(pad_hit, -1, idx[:n]))
+        if n_pad > n:
+            # |coordinates| <= M puts real pairs within d·(2M)² and pads at
+            # least d·(3M+1)² from every real point
+            far = 4.0 * jnp.max(jnp.abs(x.astype(jnp.float32))) + 1.0
+            x = jnp.concatenate(
+                [x, jnp.full((n_pad - n, x.shape[1]), far, x.dtype)])
+        out = search(x)
+        dist2, idx = out[:2]
+        if n_pad > n:
+            pad_hit = idx[:n] >= n
+            dist2 = jnp.where(pad_hit, jnp.inf, dist2[:n])
+            idx = jnp.where(pad_hit, -1, idx[:n])
+        if not with_tiles:
+            return dist2, idx
+        return dist2, idx, out[2].sum(0) if counted else None
 
     return knn
 

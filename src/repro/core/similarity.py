@@ -196,7 +196,8 @@ def build_knn_graph(
     block_q: Optional[int] = None,  # None → per-method default (256 exact
     block_k: Optional[int] = None,  # search tile, 1024 rerank chunk)
     interpret: bool | None = None,
-) -> COO:
+    with_tiles: bool = False,
+):
     """End-to-end device Stage 1: kNN search → similarity → symmetric
     row-sorted COO.  jit-safe (static nnz = 2·n·k); no host neighbor loop.
 
@@ -217,8 +218,13 @@ def build_knn_graph(
     the kNN search into a degree-capped ε-ball (neighbors beyond the radius
     are dropped).  With ``measure="exp_decay"`` and ``points=None`` the
     search distances are reused directly — no second gather pass.
+
+    Returns the COO; ``with_tiles=True`` returns ``(COO, tiles)``, where
+    ``tiles`` is ``knn_topk``'s int32 [2] count of candidate tiles merged
+    and visited (``None`` on the jnp reference and the LSH path).
     """
     p = x if points is None else points
+    tiles = None
     if points is not None and points.shape[0] != x.shape[0]:
         raise ValueError(
             f"points rows ({points.shape[0]}) must match feature rows "
@@ -233,13 +239,15 @@ def build_knn_graph(
         # NB: eps is NOT threaded into the search here — graph_from_knn
         # applies the radius mask, exactly as before the method= split
         # (keeps the exact path bitwise-unchanged)
-        dist2, idx = knn_topk(p, k, impl=impl, block_q=block_q or 256,
-                              block_k=block_k or 256, interpret=interpret)
+        dist2, idx, tiles = knn_topk(p, k, impl=impl, block_q=block_q or 256,
+                                     block_k=block_k or 256,
+                                     interpret=interpret, with_tiles=True)
     else:  # pragma: no cover - guarded by Literal / GraphConfig validation
         raise ValueError(f"unknown method {method!r} (expected 'exact'|'lsh')")
-    return graph_from_knn(x, dist2, idx, measure=measure, sigma=sigma, eps=eps,
-                          clip_negative=clip_negative,
-                          dist2_in_x_space=points is None)
+    w = graph_from_knn(x, dist2, idx, measure=measure, sigma=sigma, eps=eps,
+                       clip_negative=clip_negative,
+                       dist2_in_x_space=points is None)
+    return (w, tiles) if with_tiles else w
 
 
 # ---------------------------------------------------------------------------

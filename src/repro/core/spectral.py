@@ -352,6 +352,7 @@ class GraphState(NamedTuple):
     adj: Union[COO, ShardedCOO]  # D^{-1/2} W D^{-1/2}
     deg: Array  # [n] degrees of the raw graph
     inv_sqrt_deg: Array  # [n] D^{-1/2} (0 where isolated)
+    knn_tiles: Any = None  # [2] int32 knn_topk tiles merged, visited
 
 
 class EmbedState(NamedTuple):
@@ -712,16 +713,17 @@ class SpectralPipeline:
                 method=g.method, n_tables=g.n_tables, n_bits=g.n_bits,
                 candidates=g.candidates, lsh_seed=g.lsh_seed,
                 exchange=self.plan.stage1_exchange)
-            dist2, idx = knn(p)
+            dist2, idx, tiles = knn(p, with_tiles=True)
             w = graph_from_knn(x, dist2, idx, measure=g.measure, sigma=g.sigma,
                                eps=g.eps, dist2_in_x_space=points is None)
-            return self._normalize(w)
-        w = build_knn_graph(
+            return self._normalize(w)._replace(knn_tiles=tiles)
+        w, tiles = build_knn_graph(
             x, g.knn_k, points=points, measure=g.measure, sigma=g.sigma,
             eps=g.eps, method=g.method, n_tables=g.n_tables, n_bits=g.n_bits,
             candidates=g.candidates, lsh_seed=g.lsh_seed, impl=g.impl,
-            block_q=g.block_q, block_k=g.block_k, interpret=g.interpret)
-        return self._normalize(w)
+            block_q=g.block_q, block_k=g.block_k, interpret=g.interpret,
+            with_tiles=True)
+        return self._normalize(w)._replace(knn_tiles=tiles)
 
     # -- Stage 2 ------------------------------------------------------------
 

@@ -85,6 +85,8 @@ def _put_graph(tree, meta, name, g) -> None:
     _put_coo(tree, meta, f"{name}.adj", g.adj)
     tree[f"{name}.deg"] = np.asarray(g.deg)
     tree[f"{name}.inv_sqrt_deg"] = np.asarray(g.inv_sqrt_deg)
+    if g.knn_tiles is not None:
+        tree[f"{name}.knn_tiles"] = np.asarray(g.knn_tiles)
 
 
 def _get_graph(tree, meta, name):
@@ -92,7 +94,8 @@ def _get_graph(tree, meta, name):
 
     return GraphState(adj=_get_coo(tree, meta, f"{name}.adj"),
                       deg=jnp.asarray(tree[f"{name}.deg"]),
-                      inv_sqrt_deg=jnp.asarray(tree[f"{name}.inv_sqrt_deg"]))
+                      inv_sqrt_deg=jnp.asarray(tree[f"{name}.inv_sqrt_deg"]),
+                      knn_tiles=_optional(tree, f"{name}.knn_tiles"))
 
 
 def state_to_tree(state, pipeline=None) -> Dict[str, np.ndarray]:

@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels._util import pad_to as _pad_to, round_up as _round_up
-from repro.kernels.knn_topk.kernel import knn_topk_pallas
+from repro.kernels.knn_topk.kernel import MERGED, VISITED, knn_topk_pallas
 from repro.kernels.knn_topk.ref import knn_topk_ref
 
 
@@ -47,7 +47,8 @@ def knn_topk_engine(impl: str = "auto", interpret: bool | None = None) -> str:
     return "pallas-interpret" if interpret else "pallas"
 
 
-@partial(jax.jit, static_argnames=("k", "block_q", "block_k", "impl", "interpret"))
+@partial(jax.jit, static_argnames=("k", "block_q", "block_k", "impl", "interpret",
+                                   "with_tiles"))
 def knn_topk(
     x: jax.Array,  # [n, d] candidate points
     k: int,
@@ -59,10 +60,15 @@ def knn_topk(
     block_k: int = 256,
     impl: str = "auto",  # "auto" | "pallas" | "ref"
     interpret: bool | None = None,
+    with_tiles: bool = False,
 ):
     """dist²[i, :], idx[i, :] = the k nearest neighbors of x_i (self excluded),
-    ascending by distance.  Invalid slots (k ≥ n, or beyond ``eps``) are
-    (+inf, -1).
+    ascending by (distance, id).  Invalid slots (k ≥ n, or beyond ``eps``)
+    are (+inf, -1).
+
+    ``with_tiles=True`` appends the kernel's tile counter: int32 [2], the
+    candidate tiles it merged and the tiles it visited, summed over query
+    blocks (``None`` on the jnp reference, which has no tiles).
 
     ``queries``/``query_offset`` serve the row-block sharded Stage 1: a shard
     passes its local row block and its global row offset (traced —
@@ -75,6 +81,7 @@ def knn_topk(
     n, d = x.shape
     assert k >= 1, k
     engine = knn_topk_engine(impl, interpret)
+    tiles = None
     if engine == "ref":
         dist, idx = knn_topk_ref(x, k, queries=queries,
                                  query_offset=query_offset)
@@ -90,13 +97,18 @@ def knn_topk(
         d_p = _round_up(d, 128)
 
         xf = _pad_to(_pad_to(x.astype(jnp.float32), nc_p, 0), d_p, 1)
-        qf = _pad_to(_pad_to(q.astype(jnp.float32), nq_p, 0), d_p, 1)
+        # pad rows repeat the last query, so they need the tiles it needs
+        # (rows of zeros would pull the merges toward the origin's tiles)
+        qf = jnp.pad(q.astype(jnp.float32), ((0, nq_p - nq), (0, 0)),
+                     mode="edge")
+        qf = _pad_to(qf, d_p, 1)
         cn = (xf * xf).sum(1)
         if nc_p > n:  # padded candidates must never enter the top-k
             cn = cn.at[n:].set(jnp.inf)
-        raw, idx = knn_topk_pallas(qf, xf, cn[None, :], k_pad,
-                                   query_offset=query_offset,
-                                   block_q=bq, block_k=bk, interpret=interpret)
+        raw, idx, counts = knn_topk_pallas(
+            qf, xf, cn[None, :], k_pad, query_offset=query_offset,
+            block_q=bq, block_k=bk, interpret=interpret)
+        tiles = counts[::8, [MERGED, VISITED]].sum(0)  # one row a block
         raw, idx = raw[:nq, :k], idx[:nq, :k]
         qn = (q.astype(jnp.float32) ** 2).sum(1)
         invalid = jnp.isinf(raw)
@@ -107,7 +119,7 @@ def knn_topk(
         beyond = dist > jnp.asarray(eps, jnp.float32) ** 2
         dist = jnp.where(beyond, jnp.inf, dist)
         idx = jnp.where(beyond, -1, idx)
-    return dist, idx
+    return (dist, idx, tiles) if with_tiles else (dist, idx)
 
 
 @partial(jax.jit, static_argnames=("k", "block_q"))

@@ -397,6 +397,42 @@ def test_elastic_resharding():
     """))
 
 
+def test_ring_counts_knn_tiles_over_steps_and_chips():
+    """On four chips the ring's Stage 1 sums the kernel's tile counter over
+    its steps and chips: each chip's count is that of its own knn_topk
+    calls, one per visiting block."""
+    print(_run("""
+        import numpy as np, jax, jax.numpy as jnp
+        from jax.sharding import Mesh
+        from repro.core.spectral import GraphConfig, Plan, SpectralPipeline
+        from repro.kernels.knn_topk.ops import knn_topk
+        mesh = Mesh(np.array(jax.devices()[:4]), ("data",))
+        side = np.arange(13, dtype=np.float32)
+        x = np.stack(np.meshgrid(side, side, side, indexing="ij"),
+                     -1).reshape(-1, 3)[:2048]
+        nl, kw = 512, dict(impl="pallas", interpret=True, block_q=128)
+        graph = GraphConfig(knn_k=16, **kw)
+        pipe = SpectralPipeline(n_clusters=4, graph=graph, plan=Plan(
+            device="sharded", mesh=mesh, stage1_exchange="ring"))
+        got = np.asarray(jax.jit(pipe.build_graph)(jnp.asarray(x)).knn_tiles)
+        want = sum(np.asarray(knn_topk(
+            jnp.asarray(x[src * nl:(src + 1) * nl]), 16,
+            queries=jnp.asarray(x[my * nl:(my + 1) * nl]),
+            query_offset=(my - src) * nl, with_tiles=True, **kw)[2])
+            for my in range(4) for src in range(4))
+        assert got.tolist() == want.tolist(), (got, want)
+        merged, visited = got.tolist()
+        assert visited == 16 * 4 * 2 and 0 < merged < visited, got
+        single = SpectralPipeline(n_clusters=4, graph=graph)
+        assert single.build_graph(jnp.asarray(x)).knn_tiles is not None
+        lsh = SpectralPipeline(n_clusters=4, graph=GraphConfig(
+            knn_k=16, method="lsh"), plan=Plan(device="sharded", mesh=mesh,
+                                               stage1_exchange="ring"))
+        assert jax.jit(lsh.build_graph)(jnp.asarray(x)).knn_tiles is None
+        print("RING-TILES-OK")
+    """))
+
+
 def test_ring_exact_bitwise_matches_gather_and_single_device():
     print(_run("""
         import numpy as np, jax, jax.numpy as jnp

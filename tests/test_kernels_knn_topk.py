@@ -192,3 +192,160 @@ def test_lattice_blocks_merged_in_ring_order_equal_full_pool():
                             jnp.where(i_t >= 0, i_t + src * nb, -1), k)
     np.testing.assert_array_equal(np.asarray(bi), np.asarray(i_full))
     np.testing.assert_array_equal(np.asarray(bd), np.asarray(d_full))
+
+
+# ---------------------------------------------------------------------------
+# Nearest-first visit order, ties by id, and the skipped merges
+# ---------------------------------------------------------------------------
+
+def _grid_points(n, seed, step=0.25, side=16):
+    """Random points on a grid fine enough to be random and coarse enough
+    that every squared distance is exact in float32, so the kernel's
+    distances equal the float64 ones bit for bit."""
+    rng = np.random.default_rng(seed)
+    return (rng.integers(0, int(side / step), size=(n, 3)) * step).astype(
+        np.float32)
+
+
+def _lex_topk(xc, xq, k, off=0):
+    """Plain (dist², id) top-k of each query among the candidates; the pair
+    (query r, candidate c) is left out where ``off + r == c``."""
+    d2 = ((xq[:, None, :].astype(np.float64)
+           - xc[None, :, :].astype(np.float64)) ** 2).sum(-1)
+    d2[off + np.arange(len(xq))[:, None] == np.arange(len(xc))[None, :]] = np.inf
+    ids = np.broadcast_to(np.arange(len(xc)), d2.shape)
+    order = np.lexsort((ids, d2), axis=1)[:, :k]
+    dist = np.take_along_axis(d2, order, 1)
+    idx = np.where(np.isinf(dist), -1, order)
+    if k > len(xc):
+        pad = ((0, 0), (0, k - len(xc)))
+        dist = np.pad(dist, pad, constant_values=np.inf)
+        idx = np.pad(idx, pad, constant_values=-1)
+    return dist.astype(np.float32), idx
+
+
+def _assert_lex_topk(xc, xq, k, off=0, **kw):
+    dist, idx = knn_topk(jnp.asarray(xc), k, queries=jnp.asarray(xq),
+                         query_offset=off, impl="pallas", interpret=True, **kw)
+    want_d, want_i = _lex_topk(xc, xq, k, off)
+    np.testing.assert_array_equal(np.asarray(idx), want_i)
+    np.testing.assert_array_equal(np.asarray(dist), want_d)
+
+
+@pytest.mark.parametrize("name", ["lattice_7cubed", "lattice_300",
+                                  "random", "duplicates"])
+def test_kernel_equals_lexicographic_topk_bitwise(name):
+    """Whatever order the tiles are visited in and whichever are skipped,
+    the output is the (dist², id) top-k, bit for bit."""
+    x = {"lattice_7cubed": lambda: _lattice(343),
+         "lattice_300": lambda: _lattice(300),
+         "random": lambda: _grid_points(300, 1),
+         "duplicates": lambda: np.concatenate([_grid_points(100, 2)] * 3),
+         }[name]()
+    _assert_lex_topk(x, x, 16, block_q=32, block_k=64)
+
+
+@pytest.mark.parametrize("bq,bk", [(32, 64), (64, 32), (24, 128)])
+@pytest.mark.parametrize("shift", [-2, -1, 1, 2])
+def test_kernel_ring_offsets_bitwise(bq, bk, shift):
+    """Ring steps: the visiting block's local ids sit ``shift`` blocks away
+    from the queries', so the walk starts at its nearest boundary and no
+    self-pair exists."""
+    nl, my = 90, 2
+    x = _lattice(5 * nl)
+    src = my - shift
+    blk = x[src * nl:(src + 1) * nl]
+    _assert_lex_topk(blk, x[my * nl:(my + 1) * nl], 16, off=shift * nl,
+                     block_q=bq, block_k=bk)
+
+
+@pytest.mark.parametrize("off", [0, 49, 200])
+def test_kernel_home_and_serving_offsets_bitwise(off):
+    """A chip's own rows among all points (offset inside the pool, bq ≠ bk,
+    n not a block multiple), and serving's queries at an offset ≥ n, which
+    clamps the walk's start to the last tile."""
+    x = _lattice(200)
+    q = _grid_points(50, 3, step=0.5, side=6) if off >= 200 else x[off:off + 50]
+    _assert_lex_topk(x, q, 16, off=off, block_q=16, block_k=128)
+    _assert_lex_topk(x, q, 16, off=off, block_q=48, block_k=128)
+
+
+@pytest.mark.parametrize("nq,bq,bk,off", [(7, 8, 32, 0), (100, 16, 32, 0),
+                                          (100, 32, 16, 37), (343, 8, 128, -343)])
+def test_visit_order_is_nearest_first_permutation(nq, bq, bk, off):
+    from repro.kernels.knn_topk.kernel import visit_tile
+
+    n_tiles = 6
+    for i in range(-(-nq // bq)):
+        got = [int(visit_tile(i, j, off, block_q=bq, block_k=bk,
+                              n_tiles=n_tiles)) for j in range(n_tiles)]
+        i0 = min(max(off + i * bq, 0) // bk, n_tiles - 1)
+        want = sorted(range(n_tiles), key=lambda c: (abs(c - i0), c < i0))
+        assert got == want, (i, got, want)
+
+
+def _simulate_tiles(xc, xq, k, off, bq, bk):
+    """The skip rule in plain numpy: each query block walks the candidate
+    tiles nearest-first and merges a tile only if some row's smallest
+    distance in it is at most that row's running k_pad-th (a tie merges).
+    Returns (merged, visited).  Pad query rows repeat the last query."""
+    k_pad = -(-k // 8) * 8
+    n, nq = len(xc), len(xq)
+    n_tiles, n_blocks = -(-n // bk), -(-nq // bq)
+    c = np.zeros((n_tiles * bk, xc.shape[1])); c[:n] = xc
+    q = np.concatenate([xq, np.repeat(xq[-1:], n_blocks * bq - nq, 0)])
+    merged = 0
+    for i in range(n_blocks):
+        rows = np.arange(i * bq, (i + 1) * bq)
+        i0 = min(max(off + i * bq, 0) // bk, n_tiles - 1)
+        buf_d = np.full((bq, 0), np.inf)
+        buf_i = np.zeros((bq, 0), np.int64)
+        for t in sorted(range(n_tiles), key=lambda t: (abs(t - i0), t < i0)):
+            cols = np.arange(t * bk, (t + 1) * bk)
+            s = ((q[rows, None, :] - c[None, cols, :]) ** 2).sum(-1)
+            s[:, cols >= n] = np.inf
+            s[(off + rows)[:, None] == cols[None, :]] = np.inf
+            kth = buf_d[:, -1] if buf_d.shape[1] == k_pad else np.inf
+            if not (s.min(1) <= kth).any():
+                continue
+            merged += 1
+            d = np.concatenate([buf_d, s], 1)
+            ids = np.concatenate([buf_i, np.broadcast_to(cols, s.shape)], 1)
+            o = np.lexsort((ids, d), axis=1)[:, :k_pad]
+            buf_d = np.take_along_axis(d, o, 1)
+            buf_i = np.take_along_axis(ids, o, 1)
+    return merged, n_blocks * n_tiles
+
+
+@pytest.mark.parametrize("queries,off,bq,bk", [
+    ("all", 0, 32, 64),
+    ("all", 0, 64, 32),
+    ("block", 2 * 86, 40, 64),   # a ring step two blocks away
+    ("block", -86, 40, 64),      # a ring step one block the other way
+])
+def test_tile_counter_equals_simulation_on_a_lattice(queries, off, bq, bk):
+    x = _lattice(343)
+    xc, xq = (x, x) if queries == "all" else (x[86:172], x[:86])
+    _, _, tiles = knn_topk(jnp.asarray(xc), 16, queries=jnp.asarray(xq),
+                           query_offset=off, impl="pallas", interpret=True,
+                           block_q=bq, block_k=bk, with_tiles=True)
+    merged, visited = _simulate_tiles(xc, xq, 16, off, bq, bk)
+    assert tuple(np.asarray(tiles).tolist()) == (merged, visited)
+    if queries == "all":
+        assert merged < visited  # lattice order lets some tiles go
+
+
+def test_tile_counter_merges_nearly_every_tile_of_shuffled_points():
+    x = _lattice(343)[np.random.default_rng(0).permutation(343)]
+    _, _, tiles = knn_topk(jnp.asarray(x), 16, impl="pallas", interpret=True,
+                           block_q=32, block_k=32, with_tiles=True)
+    merged, visited = np.asarray(tiles).tolist()
+    assert visited == 11 * 11
+    assert merged >= 0.9 * visited
+
+
+def test_tile_counter_is_absent_on_the_reference():
+    x = jnp.asarray(_lattice(64))
+    out = knn_topk(x, 4, impl="ref", with_tiles=True)
+    assert len(out) == 3 and out[2] is None
+    assert len(knn_topk(x, 4, impl="ref")) == 2

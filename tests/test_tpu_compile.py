@@ -62,13 +62,28 @@ def _compile(fn, one_chip, *shapes):
 F32, I32 = jnp.float32, jnp.int32
 
 
-@pytest.mark.parametrize("d,k", [(3, 16), (90, 10), (200, 64)],
-                         ids=["dti_stage1", "serving_oos", "merge_vmem_cap"])
-def test_knn_topk_compiles(one_chip, d, k):
-    from repro.kernels.knn_topk.ops import knn_topk
+@pytest.mark.parametrize("d,k,ring", [(3, 16, False), (90, 10, False),
+                                      (200, 64, False), (3, 16, True)],
+                         ids=["dti_stage1", "serving_oos", "merge_vmem_cap",
+                              "ring_step"])
+def test_knn_topk_compiles(one_chip, d, k, ring):
+    """The kernel with its scalar-prefetched visit order, the merge's
+    predicate and the tile counter.  ``ring_step`` is a ring call of the
+    four-chip DTI cell: a chip's 7,127 rows against a visiting block at a
+    traced offset, ``block_q=1024`` capped to 480 rows by the merge's VMEM
+    budget."""
+    from repro.kernels.knn_topk.ops import _merge_rows, knn_topk
 
-    _compile(lambda x: knn_topk(x, k, impl="pallas", interpret=False),
-             one_chip, ((4096, d), F32))
+    if not ring:
+        _compile(lambda x: knn_topk(x, k, impl="pallas", interpret=False,
+                                    with_tiles=True),
+                 one_chip, ((4096, d), F32))
+        return
+    assert _merge_rows(16, 256) == 480
+    _compile(lambda x, q, off: knn_topk(x, k, queries=q, query_offset=off,
+                                        block_q=1024, impl="pallas",
+                                        interpret=False, with_tiles=True),
+             one_chip, ((7127, d), F32), ((7127, d), F32), ((), I32))
 
 
 @pytest.mark.parametrize("k,d", [(500, 500), (1024, 639)],

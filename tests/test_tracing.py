@@ -14,7 +14,9 @@ import repro.core.lanczos as lz
 from repro.core import state_io
 from repro.core.chebyshev import operator_streams
 from repro.core.operator import CooOperator
-from repro.core.spectral import EigConfig, EmbedState, SpectralPipeline
+from repro.core.spectral import (EigConfig, EmbedState, GraphConfig,
+                                 GraphState, SpectralPipeline)
+from repro.kernels.knn_topk.ops import knn_topk
 from repro.serve.batcher import BatchConfig, MicroBatcher
 from repro.testing import faults
 
@@ -162,6 +164,51 @@ def test_state_io_restores_a_checkpoint_without_the_count():
                      e.converged)
     assert old.operator_applications is None
     assert pipe.cluster(old, KEY).operator_applications is None
+
+
+def _lattice_pipeline(**graph):
+    side = np.arange(7, dtype=np.float32)
+    x = np.stack(np.meshgrid(side, side, side, indexing="ij"), -1).reshape(-1, 3)
+    cfg = dict(knn_k=16, impl="pallas", interpret=True, block_q=32, block_k=64)
+    cfg.update(graph)
+    return SpectralPipeline(n_clusters=3, graph=GraphConfig(**cfg)), jnp.asarray(x)
+
+
+def test_knn_tiles_counted_by_the_one_chip_pipeline():
+    """Stage 1 carries the kernel's merged and visited tiles: on a lattice
+    in lattice order some tiles go unmerged."""
+    pipe, x = _lattice_pipeline()
+    st = pipe.run_state(x, KEY)
+    _, _, want = knn_topk(x, 16, impl="pallas", interpret=True, block_q=32,
+                          block_k=64, with_tiles=True)
+    got = np.asarray(st.graph.knn_tiles)
+    assert got.dtype == np.int32 and got.tolist() == np.asarray(want).tolist()
+    merged, visited = got.tolist()
+    assert visited == 11 * 6 and 0 < merged < visited
+
+
+@pytest.mark.parametrize("graph", [dict(impl="ref", interpret=None),
+                                   dict(method="lsh")], ids=["ref", "lsh"])
+def test_knn_tiles_absent_without_the_kernel(graph):
+    pipe, x = _lattice_pipeline(**graph)
+    assert pipe.build_graph(x).knn_tiles is None
+
+
+def test_state_io_round_trips_knn_tiles(tmp_path):
+    pipe, x = _lattice_pipeline()
+    st = pipe.run_state(x, KEY)
+    tiles = np.asarray(st.graph.knn_tiles).tolist()
+    state_io.save_state(str(tmp_path / "new"), st, pipe)
+    back, _ = state_io.load_state(str(tmp_path / "new"))
+    assert np.asarray(back.graph.knn_tiles).tolist() == tiles
+    # a checkpoint written before the field existed restores None
+    tree = state_io.state_to_tree(st, pipe)
+    del tree["graph.knn_tiles"]
+    old, _ = state_io.state_from_tree(tree)
+    assert old.graph.knn_tiles is None
+    np.testing.assert_array_equal(old.graph.deg, st.graph.deg)
+    g = st.graph
+    assert GraphState(g.adj, g.deg, g.inv_sqrt_deg).knn_tiles is None
 
 
 def _batcher_spans(trace_dir):
