@@ -14,11 +14,10 @@ One chip runs four phases in this process, in order:
    90-dim profiles, thick-restart Lanczos (tol 1e-4), fused k-means at k=500 —
    through ``SpectralPipeline.run`` under one ``jax.jit``, compiled ahead and
    timed apart from its one run;
-4. serving: ``serve_online`` (``MicroBatcher`` -> ``serve_fn``) on
-   ``bench_serving``'s blob pool (20,000 points, d=16, k=16) with 32
-   requests.  Larger blob pools (65,536 points, k=64) leave the top
-   eigenvalue k-fold degenerate, and Lanczos then escalates through every
-   attempt without reaching ``tol``.
+4. serving: ``serve_online`` (``MicroBatcher`` -> ``serve_fn``) on a blob
+   pool of 20,000 points, d=16, k=16, with 32 requests.  Larger blob pools
+   (65,536 points, k=64) leave the top eigenvalue k-fold degenerate, and
+   Lanczos then escalates through every attempt without reaching ``tol``.
 
 ``--chips 4`` runs only the sharded path and its comparison: the DTI points
 on a 4-device mesh under ``Plan(device="sharded")`` with the gather and the

@@ -2,7 +2,7 @@
 
     PYTHONPATH=src python examples/ann_retrieval.py
 
-The autoint ``retrieval_cand`` cell scores 1 query against 10⁶ candidates.
+Candidate retrieval scores each query against a large candidate pool.
 This example builds the paper-motivated accelerator for it: cluster the
 candidate embeddings with the fast k-means (k-means++ + BLAS-trick assign),
 then at query time score only the top-``nprobe`` clusters.  Reports

@@ -1,5 +1,3 @@
-"""Fault-tolerant checkpointing: atomic versioned saves, auto-resume,
-elastic resharding onto a different mesh."""
+"""Fault-tolerant checkpointing: atomic versioned saves and auto-resume."""
 
 from repro.ckpt.manager import CheckpointManager  # noqa: F401
-from repro.ckpt.elastic import reshard_tree  # noqa: F401

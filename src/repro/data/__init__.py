@@ -1,6 +1,5 @@
-"""Data pipeline: synthetic dataset generators matching the paper's four
-datasets + token/graph/recsys batch sources and the neighbor sampler."""
+"""Synthetic dataset generators matching the paper's datasets: planted-
+partition graphs and DTI-like point clouds."""
 
 from repro.data.sbm import sbm_graph  # noqa: F401
 from repro.data.pointcloud import dti_like_pointcloud  # noqa: F401
-from repro.data.sampler import NeighborSampler  # noqa: F401

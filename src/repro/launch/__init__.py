@@ -1,1 +1,1 @@
-"""Launcher layer: production mesh, sharding rules, dry-run, train/serve."""
+"""Launcher layer: the compile cache and the serving launcher."""

@@ -1,16 +1,14 @@
-"""Serving launcher: batched spectral-clustering jobs, online OOS, LM decode.
+"""Serving launcher: batched spectral-clustering jobs and online OOS labels.
 
     python -m repro.launch.serve --mode cluster --n 20000 --clusters 64
     python -m repro.launch.serve --mode serve --n 4000 --clusters 8 \\
         --requests 64 --registry-dir /tmp/reg
-    python -m repro.launch.serve --mode decode --arch qwen3-0.6b --smoke
 
 ``cluster`` mode is the paper's serving shape: accept graphs, return labels
 (the batched-requests analogue for a clustering system).  ``serve`` mode is
 the online subsystem (:mod:`repro.serve`): train one index, answer point
 queries via out-of-sample extension through the micro-batcher — no
-eigensolve per request.  ``decode`` mode runs the LM decode path with a KV
-cache (one compiled step, stepped N times).
+eigensolve per request.  Either mode exits with its failure count.
 """
 from __future__ import annotations
 
@@ -20,7 +18,6 @@ import time
 import jax
 import jax.numpy as jnp
 
-from repro.configs import ARCHS
 from repro.launch.cache import enable_compile_cache
 
 
@@ -283,7 +280,7 @@ def serve_online(args) -> dict:
     }
     # diagnostic: re-serve the pool through OOS — labels should reproduce
     # the training clustering (the cheap in-process parity signal; the
-    # held-out gate lives in benchmarks/bench_serving.py)
+    # held-out gate is tests/test_serving.py's OOS parity test)
     pool_out = serve_fn(index, jnp.asarray(pool[:min(args.n, 2048)]))
     summary["train_ari_vs_served"] = round(adjusted_rand_index(
         np.asarray(pool_out.labels),
@@ -292,35 +289,9 @@ def serve_online(args) -> dict:
     return summary
 
 
-def serve_decode(args):
-    from repro.models import transformer as tfm
-
-    arch = ARCHS[args.arch]
-    cfg = arch.smoke_config if args.smoke else arch.config
-    params = tfm.init_params(cfg, jax.random.PRNGKey(0))
-    B, S = args.batch, args.seq
-    prompt = jax.random.randint(jax.random.PRNGKey(1), (B, S // 2), 0, cfg.vocab)
-    logits, cache = jax.jit(lambda p, t: tfm.prefill(p, t, cfg))(params, prompt)
-    cache = {k: jnp.pad(v, ((0, 0), (0, 0), (0, S - S // 2), (0, 0), (0, 0)))
-             for k, v in cache.items()}
-    step = jax.jit(lambda p, c, cl, t: tfm.decode_step(p, c, cl, t, cfg),
-                   donate_argnums=(1,))
-    tok = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)
-    cl = jnp.full((B,), S // 2, jnp.int32)
-    t0 = time.perf_counter()
-    for i in range(args.tokens):
-        logits, cache = step(params, cache, cl, tok)
-        tok = jnp.argmax(logits[:, 0], -1).astype(jnp.int32)
-        cl = cl + 1
-    jax.block_until_ready(tok)
-    dt = time.perf_counter() - t0
-    print(f"decoded {args.tokens} tokens x batch {B}: "
-          f"{args.tokens * B / dt:.1f} tok/s ({dt/args.tokens*1e3:.1f} ms/step)")
-
-
 def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--mode", choices=["cluster", "serve", "decode"],
+    ap.add_argument("--mode", choices=["cluster", "serve"],
                     default="cluster")
     ap.add_argument("--n", type=int, default=8000)
     ap.add_argument("--clusters", type=int, default=16)
@@ -351,27 +322,19 @@ def build_parser() -> argparse.ArgumentParser:
                     help="poison every odd request (nan-graph: cluster mode; "
                          "nan-query: serve mode) — fault-isolation smoke: "
                          "the loop must survive, exit code counts them)")
-    ap.add_argument("--arch", default="qwen3-0.6b")
-    ap.add_argument("--smoke", action="store_true")
-    ap.add_argument("--batch", type=int, default=2)
-    ap.add_argument("--seq", type=int, default=64)
-    ap.add_argument("--tokens", type=int, default=16)
     return ap
 
 
 def main(argv=None):
+    import sys
+
     args = build_parser().parse_args(argv)
     enable_compile_cache()
-    if args.mode in ("cluster", "serve"):
-        import sys
-
-        failures = (serve_cluster(args) if args.mode == "cluster"
-                    else serve_online(args)["failures"])
-        # exit code = failure count (clamped below the shell's reserved
-        # range) so orchestrators see partial failure without log parsing
-        sys.exit(min(failures, 125))
-    else:
-        serve_decode(args)
+    failures = (serve_cluster(args) if args.mode == "cluster"
+                else serve_online(args)["failures"])
+    # exit code = failure count (clamped below the shell's reserved range)
+    # so orchestrators see partial failure without log parsing
+    sys.exit(min(failures, 125))
 
 
 if __name__ == "__main__":

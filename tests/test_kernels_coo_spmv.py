@@ -340,3 +340,104 @@ def test_sharded_plan_dispatch_takes_row_blocks(monkeypatch):
         op, notes = SpectralPipeline(
             n_clusters=12, eig=eig, plan=plan)._operator_with_notes(state)
         assert isinstance(op, CooOperator) and notes == ()
+
+
+# -- build_row_tiles: each chip's block built on the chips (DESIGN.md §20) ---
+
+def _idle_shard():
+    """Nonzeros only in rows below 2,501 of 5,001, columns anywhere: on two
+    chips the second owns none, on four the last two."""
+    return _random(5001, 20000, rows=np.arange(2501), seed=15)
+
+
+ROW_TILE_GRAPHS = {"dti_shaped": _lattice_knn, "syn200_shaped": _planted,
+                   "idle_shard": _idle_shard}
+ROW_TILE_FIELDS = ("cols", "vals", "keys", "ends", "tile_of", "blo", "bhi",
+                   "used")
+
+ROW_TILES_SCRIPT = """
+import sys
+import numpy as np, jax, jax.numpy as jnp
+from jax.sharding import Mesh
+from repro.sparse.distributed import build_row_tiles
+from repro.sparse.formats import COO
+
+graphs, out = np.load(sys.argv[1]), {}
+for name in sys.argv[3:]:
+    row, col, val = (graphs[f"{name}/{f}"] for f in ("row", "col", "val"))
+    n = int(graphs[f"{name}/n"])
+    a = COO(jnp.asarray(row), jnp.asarray(col), jnp.asarray(val), (n, n),
+            sorted_rows=True)
+    for shards in (2, 4):
+        mesh = Mesh(np.array(jax.devices()[:shards]), ("data",))
+        t = build_row_tiles(mesh, a)
+        assert (t.n, t.rows) == (n, -(-n // shards)), (t.n, t.rows)
+        for f in %r:
+            out[f"{name}/{shards}/{f}"] = np.asarray(getattr(t, f))
+np.savez(sys.argv[2], **out)
+""" % (ROW_TILE_FIELDS,)
+
+
+@pytest.fixture(scope="module")
+def row_tiles(tmp_path_factory):
+    """build_row_tiles on two and four virtual CPU devices, in one
+    subprocess (this process keeps its one device)."""
+    import os
+    import subprocess
+    import sys
+
+    d = tmp_path_factory.mktemp("row_tiles")
+    graphs = {}
+    for name, build in ROW_TILE_GRAPHS.items():
+        a = build()
+        for f in ("row", "col", "val"):
+            graphs[f"{name}/{f}"] = np.asarray(getattr(a, f))
+        graphs[f"{name}/n"] = np.int64(a.shape[0])
+    np.savez(d / "graphs.npz", **graphs)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, PYTHONPATH=os.path.join(root, "src"),
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    out = subprocess.run(
+        [sys.executable, "-c", ROW_TILES_SCRIPT, str(d / "graphs.npz"),
+         str(d / "tiles.npz"), *ROW_TILE_GRAPHS],
+        capture_output=True, text=True, env=env, timeout=480)
+    assert out.returncode == 0, out.stdout + out.stderr
+    return dict(np.load(d / "tiles.npz"))
+
+
+@pytest.mark.parametrize("shards", [2, 4])
+@pytest.mark.parametrize("graph", list(ROW_TILE_GRAPHS))
+def test_row_tiles_hold_each_shards_nonzeros_once(row_tiles, graph, shards):
+    """Every nonzero of a chip's rows is in its layout exactly once, at its
+    own row and column with its own value, and nothing else is; each chip's
+    layout is the one build_tiles makes of its block on one device."""
+    a = ROW_TILE_GRAPHS[graph]()
+    n = a.shape[0]
+    rows = -(-n // shards)
+    row, col, val = (np.asarray(v) for v in (a.row, a.col, a.val))
+    build = jax.jit(build_tiles, static_argnames=("n", "rows"))
+    idle = 0
+    for s in range(shards):
+        t = {f: row_tiles[f"{graph}/{shards}/{f}"][s] for f in ROW_TILE_FIELDS}
+        one = build(a.row, a.col, a.val, n=n, r0=s * rows, rows=rows)
+        for f in ROW_TILE_FIELDS:
+            np.testing.assert_array_equal(t[f], np.asarray(getattr(one, f)),
+                                          err_msg=f"shard {s}: {f}")
+        # a slot's row: its chunk's tile, and its lane's key in that tile
+        nc = t["tile_of"].shape[0]
+        cols = t["cols"].reshape(nc, 8, 8, 128)  # chunk, vreg, sublane, lane
+        vals = t["vals"].reshape(nc, 8, 8, 128)
+        keys = t["keys"].reshape(nc, 8, 1, 128)
+        slot_row = (s * rows + t["tile_of"][:, None, None, None] * TILE_ROWS
+                    + keys)
+        held = cols >= 0
+        got = np.stack([np.broadcast_to(slot_row, cols.shape)[held],
+                        cols[held], vals[held].view(np.int32)], 1)
+        own = (row >= s * rows) & (row < (s + 1) * rows)
+        want = np.stack([row[own], col[own],
+                         val[own].astype(np.float32).view(np.int32)], 1)
+        np.testing.assert_array_equal(got[np.lexsort(got.T[::-1])],
+                                      want[np.lexsort(want.T[::-1])],
+                                      err_msg=f"shard {s}")
+        idle += not own.any()
+    assert idle == {"idle_shard": shards // 2}.get(graph, 0)

@@ -54,7 +54,7 @@ def test_smallest_algebraic_mode():
 
 
 def test_fixed_restarts_static_mode_matches():
-    """The dry-run's fixed-trip-count mode gives the same answer."""
+    """The fixed-trip-count mode gives the same answer."""
     W, coo = _sym_sparse(150, 0.08, seed=9)
     adj = normalize_sym(coo)
     mv = lambda x: spmv_coo(adj, x)
@@ -185,6 +185,35 @@ def test_operator_passes_accounting():
     # per steady cycle: (m - l)/b streams
     assert operator_passes(c1, 3) == 40 + 2 * (40 - l1)
     assert operator_passes(c4, 3) == 10 + 2 * (40 - l4) // 4
+
+
+# (n, k, b) -> the basis and the vectors kept at a restart: each cell's own
+# job (dti.job and dti_sharded4.job, syn200.job, the published DTI size of
+# dti.serve's atlas), a graph smaller than 2k, and a block of 4
+BASIS_SIZES = {
+    "dti": ((28508, 100, 1), 200, 150),
+    "syn200": ((20000, 200, 1), 400, 300),
+    "dti_full": ((142541, 500, 1), 1000, 750),
+    "n_below_2k": ((30, 20, 1), 30, 25),
+    "block_4": ((28508, 100, 4), 200, 152),
+}
+
+
+@pytest.mark.parametrize("case", list(BASIS_SIZES))
+def test_pipeline_basis_and_keep_sizes(case):
+    """The Lanczos basis and restart-keep sizes the pipeline configures,
+    as the benchmark reads them for ``stage2_ms_per_matvec``: ARPACK's
+    ncv = 2k capped at n, and k + half the excess kept, rounded up to the
+    block in block mode."""
+    from bench.deploy import lanczos_sizes
+    from repro.core.spectral import (EigConfig, SpectralPipeline,
+                                     default_basis_size)
+
+    (n, k, b), basis, keep = BASIS_SIZES[case]
+    assert default_basis_size(n, k, b) == basis
+    pipe = SpectralPipeline(n_clusters=k, eig=EigConfig(block_size=b))
+    assert lanczos_sizes(pipe, n) == {"basis": basis, "keep": keep,
+                                      "block": b}
 
 
 @settings(max_examples=8, deadline=None)

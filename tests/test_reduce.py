@@ -9,8 +9,7 @@ Property tests for :mod:`repro.core.reduce`:
   coarse node; columns of P sum to fine cluster sizes), the coarse operator
   is the Galerkin triple product PᵀWP, total edge weight is conserved;
 - quality gates: top-k Laplacian eigenvalue drift bounded, end-to-end ARI
-  ≥ 0.99× the unreduced pipeline on both reduction paths (the gate the
-  bench records in BENCH_sparsify.json);
+  ≥ 0.99× the unreduced pipeline on both reduction paths;
 - the stage DAG itself: tuple validation, serialization round-trip,
   provenance/bitwise-default behavior, sharded composition.
 """

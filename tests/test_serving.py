@@ -349,7 +349,7 @@ def test_index_problems_gate():
 
 
 # ---------------------------------------------------------------------------
-# numeric_problems (the dryrun/roofline structural gate)
+# numeric_problems (the serving loop's structural gate)
 # ---------------------------------------------------------------------------
 
 def test_numeric_problems_scans_nested_trees():
@@ -362,13 +362,44 @@ def test_numeric_problems_scans_nested_trees():
     assert "2 entries" in probs[0] and "cell" in probs[0]
 
 
-def test_roofline_analyze_raw_rejects_nonfinite():
-    from repro.launch import roofline as rl
+# ---------------------------------------------------------------------------
+# The serving launcher (python -m repro.launch.serve)
+# ---------------------------------------------------------------------------
 
-    with pytest.raises(ValueError, match="non-finite value at 'flops_dev'"):
-        rl.analyze_raw("c", "single", 8, flops_dev=float("nan"),
-                       bytes_dev=1e9, coll_by_kind={}, model_flops_total=1e12,
-                       mem_gb=1.0, compile_s=0.0)
+@pytest.mark.parametrize("argv", [
+    ["--mode", "decode"], ["--arch", "qwen3-0.6b"], ["--smoke"],
+    ["--seq", "64"], ["--tokens", "16"]], ids=lambda a: a[0].lstrip("-"))
+def test_serve_parser_has_only_the_clustering_modes(argv):
+    """The launcher has two modes, clustering jobs and OOS labels: any
+    other mode, and flags of neither, are refused as usage errors."""
+    from repro.launch.serve import build_parser
+
+    with pytest.raises(SystemExit) as e:
+        build_parser().parse_args(argv)
+    assert e.value.code == 2
+
+
+def test_serve_mode_exit_code_counts_poisoned_queries(tmp_path):
+    """``--mode serve`` with every odd request poisoned: the loop serves the
+    rest and exits with the failure count, 4 of 8."""
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, PYTHONPATH=os.path.join(root, "src"),
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
+    out = subprocess.run(
+        [sys.executable, "-m", "repro.launch.serve", "--mode", "serve",
+         "--n", "512", "--clusters", "4", "--dim", "8", "--requests", "8",
+         "--inject-fault", "nan-query",
+         "--registry-dir", str(tmp_path / "reg")],
+        capture_output=True, text=True, env=env, timeout=300)
+    assert out.returncode == 4, out.stdout + out.stderr
+    summary = json.loads(out.stdout.strip().splitlines()[-1])
+    assert summary["event"] == "serve_summary" and summary["failures"] == 4
+    errors = [json.loads(ln) for ln in out.stderr.splitlines()
+              if ln.startswith('{"event": "request_error"')]
+    assert [e["req"] for e in errors] == [1, 3, 5, 7]
 
 
 # ---------------------------------------------------------------------------

@@ -7,8 +7,9 @@ from hypothesis import given, settings, strategies as st
 
 from repro.core.similarity import (
     build_knn_graph, build_similarity_graph, edge_similarities, eps_neighbors,
-    knn_edges,
+    graph_from_knn, knn_edges,
 )
+from repro.kernels.knn_topk.ops import knn_topk
 
 
 def _dense(w, n):
@@ -105,30 +106,85 @@ def test_property_knn_degree_duplicate_points(n, dup, k, seed):
 # Device-resident Stage 1 (build_knn_graph)
 # ---------------------------------------------------------------------------
 
-def test_build_knn_graph_matches_host_path_exp_decay():
-    """Device path == host knn_edges + build_similarity_graph, up to the
-    documented ×2 symmetrization scale (host sums mirrored duplicates, device
-    averages (W+Wᵀ)/2)."""
+def _numpy_knn_graph(x, dist2, idx, *, measure, sigma, eps, dist2_in_x_space):
+    """graph_from_knn's semantics in plain numpy (float64): each valid kNN
+    slot (i, j) weighs max(0, sim(i, j)); invalid slots (id -1, or beyond
+    eps) are zero-valued self edges; the result is (W + Wᵀ)/2 as duplicate
+    coordinates, 2·n·k entries."""
+    n, k = idx.shape
+    row = np.repeat(np.arange(n), k)
+    valid = (idx >= 0).reshape(-1)
+    if eps is not None:
+        valid &= (dist2 <= np.float32(eps) ** 2).reshape(-1)
+    col = np.where(valid, idx.reshape(-1), row)
+    xf = x.astype(np.float64)
+    if measure == "exp_decay":
+        d2 = (dist2.reshape(-1).astype(np.float64) if dist2_in_x_space
+              else ((xf[row] - xf[col]) ** 2).sum(1))
+        val = np.exp(-d2 / (2.0 * sigma ** 2))
+    else:
+        if measure == "cross_correlation":
+            xf = xf - xf.mean(1, keepdims=True)
+        nrm = np.linalg.norm(xf, axis=1)
+        val = (xf[row] * xf[col]).sum(1) / np.maximum(nrm[row] * nrm[col], 1e-12)
+    val = np.where(valid, np.maximum(val, 0.0), 0.0)
+    return (np.concatenate([row, col]), np.concatenate([col, row]),
+            np.concatenate([val, val]) / 2.0)
+
+
+# build_knn_graph against the host path (knn_edges + build_similarity_graph),
+# which sums mirrored duplicates where the device path averages them
+HOST_TOL = {"exp_decay": dict(rtol=1e-4, atol=1e-6),
+            "cross_correlation": dict(rtol=2e-4, atol=1e-5),
+            "cosine": dict(rtol=2e-4, atol=1e-5)}
+
+
+@pytest.mark.parametrize("with_eps", [False, True], ids=["no_eps", "eps"])
+@pytest.mark.parametrize("dist2_in_x_space", [True, False],
+                         ids=["x_space", "search_space"])
+@pytest.mark.parametrize("measure", ["cosine", "cross_correlation", "exp_decay"])
+def test_graph_from_knn_matches_numpy_assembly(measure, dist2_in_x_space,
+                                               with_eps):
+    """Device Stage 1's graph assembly against plain numpy: the same
+    (row, col, value) entries, rows non-decreasing, and duplicate
+    coordinates that sum to (W + Wᵀ)/2; masked slots (id -1, or beyond
+    eps) carry nothing.  Without eps, build_knn_graph end to end is twice
+    the host path's graph."""
     rng = np.random.default_rng(4)
-    n, k = 180, 6
+    n, k, sigma = 120, 6, 1.3
     x = rng.normal(size=(n, 5)).astype(np.float32)
-    wd = build_knn_graph(jnp.asarray(x), k, measure="exp_decay", sigma=1.2)
-    wh = build_similarity_graph(x, knn_edges(x, k), measure="exp_decay", sigma=1.2)
-    np.testing.assert_allclose(2.0 * _dense(wd, n), _dense(wh, n), rtol=1e-4, atol=1e-6)
-    # device output contract: sorted rows, symmetric, jit-safe static nnz
-    assert wd.sorted_rows is True
-    assert (np.diff(np.asarray(wd.row)) >= 0).all()
-    assert wd.nnz == 2 * n * k
-    np.testing.assert_allclose(_dense(wd, n), _dense(wd, n).T, atol=1e-6)
-
-
-def test_build_knn_graph_matches_host_path_cross_correlation():
-    rng = np.random.default_rng(8)
-    n, k = 120, 5
-    x = rng.normal(size=(n, 12)).astype(np.float32)
-    wd = build_knn_graph(jnp.asarray(x), k, measure="cross_correlation")
-    wh = build_similarity_graph(x, knn_edges(x, k), measure="cross_correlation")
-    np.testing.assert_allclose(2.0 * _dense(wd, n), _dense(wh, n), rtol=2e-4, atol=1e-5)
+    search = x if dist2_in_x_space else rng.normal(size=(n, 3)).astype(np.float32)
+    dist2, idx = (np.array(a) for a in knn_topk(jnp.asarray(search), k,
+                                                impl="ref"))
+    eps = float(np.median(np.sqrt(dist2))) if with_eps else None
+    dist2[::7, -1], idx[::7, -1] = np.inf, -1  # masked slots
+    w = graph_from_knn(jnp.asarray(x), jnp.asarray(dist2), jnp.asarray(idx),
+                       measure=measure, sigma=sigma, eps=eps,
+                       dist2_in_x_space=dist2_in_x_space)
+    row, col, val = _numpy_knn_graph(x, dist2, idx, measure=measure,
+                                     sigma=sigma, eps=eps,
+                                     dist2_in_x_space=dist2_in_x_space)
+    gr, gc, gv = (np.asarray(a) for a in (w.row, w.col, w.val))
+    assert w.sorted_rows is True and w.nnz == 2 * n * k == row.size
+    assert (np.diff(gr) >= 0).all()
+    got, want = np.lexsort((gv, gc, gr)), np.lexsort((val, col, row))
+    np.testing.assert_array_equal(gr[got], row[want])
+    np.testing.assert_array_equal(gc[got], col[want])
+    np.testing.assert_allclose(gv[got], val[want], rtol=1e-5, atol=1e-7)
+    dense = _dense(w, n)
+    want_dense = np.zeros((n, n))
+    np.add.at(want_dense, (row, col), val)
+    np.testing.assert_allclose(dense, want_dense, rtol=1e-5, atol=1e-7)
+    np.testing.assert_allclose(dense, dense.T, atol=1e-6)
+    if eps is None:
+        points = None if dist2_in_x_space else jnp.asarray(search)
+        wd = build_knn_graph(jnp.asarray(x), k, points=points,
+                             measure=measure, sigma=sigma)
+        wh = build_similarity_graph(x, knn_edges(search, k), measure=measure,
+                                    sigma=sigma)
+        assert wd.sorted_rows is True and wd.nnz == 2 * n * k
+        np.testing.assert_allclose(2.0 * _dense(wd, n), _dense(wh, n),
+                                   **HOST_TOL[measure])
 
 
 def test_build_knn_graph_separate_points_space():
